@@ -30,6 +30,7 @@ def packed_width(d: int) -> int:
     return (d + PACK - 1) // PACK
 
 
+@jax.jit
 def pack_signs(v: jax.Array) -> jax.Array:
     """Pack sign bits of the last axis into int32 words (LSB-first).
 
@@ -38,7 +39,9 @@ def pack_signs(v: jax.Array) -> jax.Array:
     multiple of 32; padded lanes pack as positive bits, which the predictor
     accounts for via ``d_valid``.
 
-    Shape: (..., d) -> (..., ceil(d/32)) int32.
+    Shape: (..., d) -> (..., ceil(d/32)) int32.  Jitted, so packing a
+    stacked full-size gate matrix fuses into one reduction instead of
+    materializing its (..., d) uint32 bit planes.
     """
     d = v.shape[-1]
     w = packed_width(d)

@@ -491,12 +491,11 @@ def pallas_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
         sign_wg = P.pack_signs(params["wg_t"])
     a = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (b,))
     # chunk-token regime (sequence-axis prefill, DESIGN.md §9): beyond the
-    # decode kernels' resident-batch budget, the token/row-tiled twins take
-    # over — identical contracts, bitwise-equal per-row results
+    # decode MLP kernel's resident-batch budget, its row-tiled form takes
+    # over — identical contract, bitwise-equal per-row results (the
+    # predictor tiles tokens itself)
     chunked = b > cfg.sparse_max_batch
-    predict = (kops.predict_chunk_group_margins if chunked
-               else kops.predict_group_margins)
-    gm_tok, pred_cnt = predict(
+    gm_tok, pred_cnt = kops.predict_group_margins(
         sign_wg, xb, d, a, group_size=g, interpret=interpret)
     gm = S.union_margin(gm_tok)                   # (k/g,) batch/chunk union
     sel, sstats = S.capacity_select_with_stats(gm, cap)

@@ -1,10 +1,12 @@
 """Jitted public wrappers for the Pallas kernels with backend dispatch.
 
-``interpret=None`` (default) resolves to ``True`` unless running on a real
-TPU backend — so the same call sites work in this CPU container (interpret
-mode, used by tests) and on hardware (compiled Mosaic kernels).  Shapes the
-kernels can't tile (degenerate tilings now raise explicit ``ValueError``
-from ``choose_block_k`` / ``choose_blocks``) fall back to the jnp oracle.
+``interpret=None`` (default) resolves to ``True`` unless running on a TPU
+backend: on the CPU (tests, ``JAX_PLATFORMS=cpu``) the kernels run in the
+Pallas interpreter; on a TPU they compile to Mosaic kernels.  A shape a
+kernel cannot tile (``choose_block_k`` / ``choose_block_rows`` /
+``check_quant_dims`` / ``check_tiling`` raise ``ValueError``) runs the jnp
+oracle from ``ref.py`` in interpret mode only; compiled, the wrapper raises
+with the shape, so a TPU run never silently executes something else.
 """
 from __future__ import annotations
 
@@ -13,8 +15,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import predictor as P
 from repro.kernels import ref
-from repro.kernels import sign_pack as _sign_pack
 from repro.kernels import predict as _predict
 from repro.kernels import paged_attn as _paged
 from repro.kernels import sparse_mlp_fused as _fused
@@ -26,19 +28,14 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def sign_pack(v: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
-    """Pack sign bits of the last axis: (..., d) -> (..., d/32) int32."""
-    interp = _resolve_interpret(interpret)
-    if v.shape[-1] % 32 != 0:
-        return ref.sign_pack_ref(v)
-    shape = v.shape
-    flat = v.reshape(-1, shape[-1])
-    try:
-        block = _sign_pack.choose_blocks(*flat.shape)
-    except ValueError:   # degenerate tiling: explicit error -> oracle
-        return ref.sign_pack_ref(v)
-    out = _sign_pack.sign_pack(flat, interpret=interp, block=block)
-    return out.reshape(shape[:-1] + (shape[-1] // 32,))
+def _refuse_compiled(interp: bool, err: ValueError, kernel: str,
+                     **shape) -> None:
+    """Raise when a compiled kernel cannot tile ``shape``; return (so the
+    caller runs the jnp oracle) in interpret mode."""
+    if not interp:
+        dims = ", ".join(f"{k}={v}" for k, v in shape.items())
+        raise ValueError(f"{kernel}: no TPU tiling for {dims}: {err}") \
+            from err
 
 
 def predict_counts(packed_w: jax.Array, packed_x: jax.Array, *,
@@ -47,15 +44,17 @@ def predict_counts(packed_w: jax.Array, packed_x: jax.Array, *,
     interp = _resolve_interpret(interpret)
     lead = packed_x.shape[:-1]
     flat = packed_x.reshape(-1, packed_x.shape[-1])
+    k, w = packed_w.shape
     try:
-        bk = _predict.choose_block_k(packed_w.shape[0], packed_w.shape[1],
-                                     flat.shape[0])
-    except ValueError:   # degenerate tiling: explicit error -> oracle
+        bk = _predict.choose_block_k(k, w, flat.shape[0])
+    except ValueError as e:
+        _refuse_compiled(interp, e, "predict_counts", k=k, w=w,
+                         b=flat.shape[0])
         out = ref.predict_counts_ref(packed_w, flat)
     else:
         out = _predict.predict_counts(packed_w, flat, interpret=interp,
                                       block_k=bk)
-    return out.reshape(lead + (packed_w.shape[0],))
+    return out.reshape(lead + (k,))
 
 
 def predict_margins(packed_w: jax.Array, packed_x: jax.Array, d_valid: int,
@@ -72,13 +71,15 @@ def predict_group_margins(packed_w: jax.Array, x: jax.Array, d_valid: int,
                           alpha: float | jax.Array = 1.0, *,
                           group_size: int = 8,
                           interpret: Optional[bool] = None):
-    """Single-dispatch decode predictor (DESIGN.md §2): raw input (B, d) ->
-    per-token per-group margins (B, k/G) + per-slot predicted counts (B,).
+    """Fused predictor (DESIGN.md §2): raw input (B, d) -> per-token
+    per-group margins (B, k/G) + per-token predicted counts (B,), for a
+    decode batch or a prefill chunk alike.
 
-    Fuses sign-packing, XOR/popcount, the alpha margin and the group-min
-    into one Pallas kernel — no packed input or (B, k) count matrix ever
-    round-trips HBM.  Bitwise-identical to the ``core.predictor`` epilogue
-    composition it replaces.
+    The input is sign-packed in XLA (``core.predictor.pack_signs``, B·d/32
+    words); XOR/popcount, the alpha margin and the group-min run in one
+    Pallas kernel, so the (B, k) count matrix never round-trips HBM.
+    Bitwise-identical to the ``core.predictor`` epilogue composition it
+    replaces.
     """
     interp = _resolve_interpret(interpret)
     k, w = packed_w.shape
@@ -86,42 +87,14 @@ def predict_group_margins(packed_w: jax.Array, x: jax.Array, d_valid: int,
     a = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (b,))
     try:
         bk = _predict.choose_block_k(k, w, b, group_size)
-    except ValueError:   # degenerate tiling: explicit error -> oracle
+    except ValueError as e:
+        _refuse_compiled(interp, e, "predict_group_margins", k=k, w=w, b=b,
+                         group_size=group_size)
         return ref.predict_group_margins_ref(packed_w, x, d_valid, a,
                                              group_size)
-    pad = w * _predict.PACK - x.shape[-1]
-    xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     return _predict.predict_group_margins(
-        packed_w, xp, a, d_valid=d_valid, group_size=group_size,
-        interpret=interp, block_k=bk)
-
-
-def predict_chunk_group_margins(packed_w: jax.Array, x: jax.Array,
-                                d_valid: int,
-                                alpha: float | jax.Array = 1.0, *,
-                                group_size: int = 8,
-                                interpret: Optional[bool] = None):
-    """Chunked-prefill predictor (DESIGN.md §9): token-tiled twin of
-    :func:`predict_group_margins` with the identical output contract, for
-    row counts (a 64–128-token chunk) that would blow the decode kernel's
-    resident-batch VMEM budget.  Falls back to the jnp oracle on degenerate
-    tilings, exactly like the decode wrapper.
-    """
-    interp = _resolve_interpret(interpret)
-    k, w = packed_w.shape
-    b = x.shape[0]
-    a = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), (b,))
-    try:
-        bt = _predict.choose_block_tokens(b)
-        bk = _predict.choose_block_k(k, w, bt, group_size)
-    except ValueError:   # degenerate tiling: explicit error -> oracle
-        return ref.predict_chunk_group_margins_ref(packed_w, x, d_valid, a,
-                                                   group_size)
-    pad = w * _predict.PACK - x.shape[-1]
-    xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-    return _predict.predict_chunk_group_margins(
-        packed_w, xp, a, d_valid=d_valid, group_size=group_size,
-        interpret=interp, block_k=bk, block_t=bt)
+        packed_w, P.pack_signs(x), a, d_valid=d_valid,
+        group_size=group_size, interpret=interp, block_k=bk)
 
 
 def fused_sparse_mlp(x: jax.Array,
@@ -171,17 +144,19 @@ def fused_sparse_mlp_chunk(x: jax.Array,
     """Row-tiled fused sparse MLP for prefill chunks (DESIGN.md §9): one
     chunk-union selection drives every row block; per-row outputs and
     telemetry are bitwise-equal to :func:`fused_sparse_mlp` on the same
-    selection.  Degenerate row tilings fall back to the jnp oracle.
+    selection.
     """
     interp = _resolve_interpret(interpret)
     try:
         bt = _fused.choose_block_rows(x.shape[0], x.shape[1])
-    except ValueError:   # degenerate tiling: explicit error -> oracle
+    except ValueError as e:
+        _refuse_compiled(interp, e, "fused_sparse_mlp_chunk", b=x.shape[0],
+                         d=x.shape[1])
         return ref.fused_sparse_mlp_chunk_ref(
             x, wg_t, wu_t, wd_t, sel_indices, sel_count, gm_tok,
             group_size=group_size, activation=activation,
             fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats)
-    return _fused.fused_sparse_mlp_chunk(
+    return _fused.fused_sparse_mlp(
         x, wg_t, wu_t, wd_t, sel_indices, sel_count, gm_tok,
         group_size=group_size, activation=activation,
         fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats,
@@ -209,8 +184,8 @@ def fused_sparse_mlp_q(x: jax.Array,
     :func:`fused_sparse_mlp` with int8 tiles + per-group f32 scales
     (``wg_s``/``wu_s`` (k, d/qg) row-grouped, ``wd_s`` (k/qg, d) column-
     grouped).  Tilings the quant layout can't honor (qg not dividing d/k,
-    or not a multiple of the selection group) fall back to the bitwise jnp
-    oracle — same explicit-error contract as the fp wrappers.
+    or not a multiple of the selection group) raise when compiled and run
+    the bitwise jnp oracle in interpret mode.
     """
     from repro.core.quantize import check_quant_dims
     interp = _resolve_interpret(interpret)
@@ -219,7 +194,9 @@ def fused_sparse_mlp_q(x: jax.Array,
     qg = d // wg_s.shape[1]
     try:
         check_quant_dims(d, k, group_size, qg)
-    except ValueError:   # degenerate quant tiling: explicit error -> oracle
+    except ValueError as e:
+        _refuse_compiled(interp, e, "fused_sparse_mlp_q", d=d, k=k,
+                         group_size=group_size, qg=qg)
         return ref.fused_sparse_mlp_q_ref(
             x, wg_q, wg_s, wu_q, wu_s, wd_q, wd_s, sel_indices, sel_count,
             gm_tok, group_size=group_size, activation=activation,
@@ -249,8 +226,7 @@ def fused_sparse_mlp_chunk_q(x: jax.Array,
                              interpret: Optional[bool] = None,
                              groups_per_step: int = 0):
     """Row-tiled int8 fused sparse MLP for prefill chunks (DESIGN.md
-    §9/§13); falls back to the bitwise quant oracle on degenerate quant or
-    row tilings."""
+    §9/§13), with the wrapper contract of :func:`fused_sparse_mlp_q`."""
     from repro.core.quantize import check_quant_dims
     interp = _resolve_interpret(interpret)
     d = x.shape[1]
@@ -259,12 +235,15 @@ def fused_sparse_mlp_chunk_q(x: jax.Array,
     try:
         check_quant_dims(d, k, group_size, qg)
         bt = _fused.choose_block_rows(x.shape[0], d)
-    except ValueError:   # degenerate tiling: explicit error -> oracle
+    except ValueError as e:
+        _refuse_compiled(interp, e, "fused_sparse_mlp_chunk_q",
+                         b=x.shape[0], d=d, k=k, group_size=group_size,
+                         qg=qg)
         return ref.fused_sparse_mlp_chunk_q_ref(
             x, wg_q, wg_s, wu_q, wu_s, wd_q, wd_s, sel_indices, sel_count,
             gm_tok, group_size=group_size, activation=activation,
             fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats)
-    return _fused.fused_sparse_mlp_chunk_q(
+    return _fused.fused_sparse_mlp_q(
         x, wg_q, wg_s, wu_q, wu_s, wd_q, wd_s, sel_indices, sel_count,
         gm_tok, group_size=group_size, activation=activation,
         fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats,
@@ -280,19 +259,23 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Paged decode attention over KV-pool pages (DESIGN.md §10):
     q (B, H, hd) × pages (N, block, K, hd) + table (B, nbps) + lengths (B,)
     -> normalized context (B, H, hd) f32.  int8 pools (factored scales) and
-    shapes the kernel can't hold resident run the dense gather oracle."""
+    shapes the kernel can't hold resident run the dense gather oracle in
+    interpret mode and raise when compiled."""
     interp = _resolve_interpret(interpret)
-    if k_scale is not None or k_pages.dtype == jnp.int8:
-        return ref.paged_attention_ref(q, k_pages, v_pages, table, lengths,
-                                       k_scale, v_scale, softcap=softcap,
-                                       window=window)
     try:
+        if k_scale is not None or k_pages.dtype == jnp.int8:
+            raise ValueError("int8 pools have no kernel path (the factored "
+                             "scale epilogue is jnp-side)")
         _paged.check_tiling(k_pages.shape[0], k_pages.shape[1],
                             k_pages.shape[2], k_pages.shape[3],
                             k_pages.dtype.itemsize, q.shape[1])
-    except ValueError:   # degenerate/oversized pool: explicit -> oracle
+    except ValueError as e:
+        _refuse_compiled(interp, e, "paged_attention",
+                         pages=tuple(k_pages.shape), dtype=k_pages.dtype,
+                         heads=q.shape[1])
         return ref.paged_attention_ref(q, k_pages, v_pages, table, lengths,
-                                       softcap=softcap, window=window)
+                                       k_scale, v_scale, softcap=softcap,
+                                       window=window)
     return _paged.paged_attention(q, k_pages, v_pages, table, lengths,
                                   softcap=softcap, window=window,
                                   interpret=interp)
@@ -307,7 +290,9 @@ def paged_kv_write(pages: jax.Array, vals: jax.Array, blocks: jax.Array,
     try:
         _paged.check_tiling(pages.shape[0], pages.shape[1], 1, 1,
                             pages.dtype.itemsize, 1)
-    except ValueError:
+    except ValueError as e:
+        _refuse_compiled(interp, e, "paged_kv_write",
+                         pages=tuple(pages.shape))
         return ref.paged_kv_write_ref(pages, vals, blocks, offsets)
     return _paged.paged_kv_write(pages, vals, blocks, offsets,
                                  interpret=interp)
@@ -333,8 +318,8 @@ def choose_blocks(k: int, w: int, b: int, *, group_size: int = 8,
     single-group tiles).  Returns a :class:`BlockPlan`; raises
     ``ValueError`` (same contract as ``choose_block_k``) when the split is
     invalid or the local predictor grid is degenerate — the serve path
-    calls this per (bucket, shard) at construction to warn that the
-    sharded pallas predictor would fall back to the jnp oracle.
+    calls this per (bucket, shard) to refuse such a grid on the chip and
+    to warn that interpret mode runs the jnp oracle for it.
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")

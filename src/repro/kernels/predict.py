@@ -10,18 +10,25 @@ Two entry points:
     the op-count studies.
 
 ``predict_group_margins``
-    The single-dispatch decode predictor (DESIGN.md §2): fuses input
-    sign-packing, XOR/popcount, the alpha margin (paper eq. 2) and the
-    row-group min-aggregation into ONE kernel.  The packed input and the
-    (B, k) count matrix live only in VMEM — nothing round-trips HBM between
-    packing, prediction and selection.  Outputs are selection-ready per-token
-    per-group margins (B, k/G) plus per-slot predicted-group counts (B,),
-    so the whole sparse-MLP pipeline is two Pallas dispatches: this kernel,
-    then the fused MLP (kernels/sparse_mlp_fused.py).
+    The decode predictor (DESIGN.md §2): XOR/popcount, the alpha margin
+    (paper eq. 2) and the row-group min-aggregation in ONE kernel.  The
+    (B, k) count matrix lives only in VMEM; the kernel writes
+    selection-ready per-token per-group margins (B, k/G).  The input is
+    sign-packed by ``core.predictor.pack_signs`` before the call (B·d/32
+    words — the TPU compiler cannot split the lane axis into 32-bit words
+    in-kernel).  Prefill chunks use the same kernel: tokens are tiled by 8,
+    and taken one at a time within a tile.
 
 Reads ``k·d/8`` bytes of packed weight signs — 16× fewer than one bf16
 weight matrix — making prediction a ~6% overhead on the dense MLP's traffic
 (paper Table I: 2.2e6 predictor ops vs 2.1e8 MLP MACs for 13B).
+
+Tiling: the k axis is cut into tiles whose OUTPUT width is a multiple of
+the 128-lane vreg (``G·128`` rows for the group kernel, 128 for counts) or
+the whole of k, and the grid is ``cdiv`` — the last tile may overhang k,
+and its out-of-range lanes are never written back.  So any ``k`` divisible
+by ``G`` tiles, including k/G = 1376 (ProSparse-Llama2-7B), whose only
+divisors are not lane-aligned.
 """
 from __future__ import annotations
 
@@ -31,46 +38,59 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-PACK = 32
-
-
-def _predict_kernel(pw_ref, px_ref, out_ref):
-    pw = pw_ref[...]                      # (bk, w) int32
-    px = px_ref[...]                      # (B, w) int32
-    xor = jnp.bitwise_xor(px[:, None, :], pw[None, :, :])     # (B, bk, w)
-    counts = jnp.sum(jax.lax.population_count(xor), axis=-1)  # (B, bk)
-    out_ref[...] = counts.astype(jnp.int32)
+LANES = 128          # vreg lane width: output tiles are multiples of it
+TOKEN_TILE = 8       # f32 sublanes: tokens per grid step
+# Half of a v5e core's 16 MiB scoped VMEM, for what scales with the k-tile:
+# the double-buffered (bk, w) int32 packed-weight tile plus ONE token's
+# (bk, w) XOR and popcount temporaries (the kernels loop over the token
+# tile), i.e. 16·w bytes per k row.
+VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+BYTES_PER_ROW_WORD = 4 * (2 + 2)
 
 
 def choose_block_k(k: int, w: int, b: int, group_size: int = 1) -> int:
-    """Tile k so the (B, bk, w) int32 intermediate stays under ~4 MiB.
+    """k-tile for the predictor kernels.
 
-    Raises ``ValueError`` on degenerate tilings instead of silently falling
-    back to worst-case 1-row tiles (satellite: tiling guards): the ``ops``
-    dispatch layer catches the error and routes to the jnp oracle.
+    The tile's output width ``bk / group_size`` must be a multiple of 128
+    lanes, or the tile must be the whole of ``k``; the tile's VMEM
+    (``BYTES_PER_ROW_WORD · w`` bytes per row) stays under
+    ``VMEM_BUDGET_BYTES``.  The batch does not enter the budget: tokens are
+    processed one at a time within a tile, so one G·128-row tile fits for
+    any batch up to w = 512 words (d = 16384).  Raises ``ValueError`` when
+    no such tile exists — the ``ops`` layer then runs the jnp oracle in
+    interpret mode and refuses the shape on the chip.
     """
     if k <= 0 or w <= 0 or b <= 0:
         raise ValueError(f"predictor tiling needs k,w,b > 0, got "
                          f"k={k} w={w} b={b}")
     if k % group_size:
         raise ValueError(f"k={k} not divisible by group_size={group_size}")
-    budget = (4 * 1024 * 1024) // (4 * w * b)
-    if budget < min(k, 8):
+    budget = VMEM_BUDGET_BYTES // (BYTES_PER_ROW_WORD * w)
+    if k <= budget:
+        return k
+    unit = group_size * LANES
+    bk = budget - budget % unit
+    if bk < unit:
         raise ValueError(
-            f"degenerate predictor tile: batch×width b={b}, w={w} words "
-            f"leaves a k-tile budget of {budget} rows (< 8) — shrink the "
-            "batch or use the jnp reference path")
-    bk = min(k, budget)
-    bk -= bk % group_size
-    while bk > 0 and k % bk:
-        bk -= group_size
-    if bk < min(k, 8):
-        raise ValueError(
-            f"no non-degenerate k-tile for k={k} (group={group_size}, "
-            f"budget={budget}): largest divisor found is {max(bk, 0)} — pad "
-            "k to a composite multiple of the group size or use the jnp "
-            "reference path")
+            f"degenerate predictor tile: k={k}, w={w} words leave a k-tile "
+            f"budget of {budget} rows, below one lane-aligned tile of "
+            f"{unit} rows (group_size={group_size})")
     return bk
+
+
+def _token_counts(px_ref, pw, t: int) -> jax.Array:
+    """Negative-product counts of token ``t`` of the tile against the
+    (bk, w) weight tile: (1, bk) int32.  One token at a time keeps the
+    XOR intermediate at (bk, w) whatever the token tile."""
+    px = px_ref[t:t + 1, :]                                   # (1, w)
+    xor = jnp.bitwise_xor(px[:, None, :], pw[None, :, :])     # (1, bk, w)
+    return jnp.sum(jax.lax.population_count(xor), axis=-1, dtype=jnp.int32)
+
+
+def _predict_kernel(pw_ref, px_ref, out_ref):
+    pw = pw_ref[...]                      # (bk, w) int32
+    for t in range(px_ref.shape[0]):
+        out_ref[t:t + 1, :] = _token_counts(px_ref, pw, t)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_k"))
@@ -81,201 +101,84 @@ def predict_counts(packed_w: jax.Array, packed_x: jax.Array, *,
     k, w = packed_w.shape
     b = packed_x.shape[0]
     bk = block_k or choose_block_k(k, w, b)
-    grid = (k // bk,)
+    bt = min(b, TOKEN_TILE)
     return pl.pallas_call(
         _predict_kernel,
-        grid=grid,
+        grid=(pl.cdiv(k, bk), pl.cdiv(b, bt)),
         in_specs=[
-            pl.BlockSpec((bk, w), lambda i: (i, 0)),
-            pl.BlockSpec((b, w), lambda i: (0, 0)),
+            pl.BlockSpec((bk, w), lambda i, t: (i, 0)),
+            pl.BlockSpec((bt, w), lambda i, t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((b, bk), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((bt, bk), lambda i, t: (t, i)),
         out_shape=jax.ShapeDtypeStruct((b, k), jnp.int32),
         interpret=interpret,
     )(packed_w, packed_x)
 
 
 def _make_group_margins_kernel(d_valid: int, group_size: int):
-    """Fused sign-pack + XOR/popcount + alpha margin + group-min kernel.
+    """Fused XOR/popcount + alpha margin + group-min kernel.
 
-    The packing and margin arithmetic reproduce ``core.predictor`` bitwise
-    (same op sequence in the same dtypes), so the selection downstream is
+    The margin arithmetic reproduces ``core.predictor`` bitwise (same op
+    sequence in the same dtypes), so the selection downstream is
     bit-identical to the multi-dispatch path it replaces.
     """
-    def kernel(x_ref, pw_ref, alpha_ref, gm_ref, cnt_ref):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-        # pack input sign bits in-register (cheap VPU work recomputed per
-        # k-tile; x stays VMEM-resident — its block index never changes)
-        x = x_ref[...]                                   # (B, dp)
-        b, dp = x.shape
-        bits = (x < 0).astype(jnp.uint32)
-        bits = bits.reshape(b, dp // PACK, PACK)
-        weights = jnp.uint32(1) << jnp.arange(PACK, dtype=jnp.uint32)
-        px = jnp.sum(bits * weights, axis=-1,
-                     dtype=jnp.uint32).astype(jnp.int32)  # (B, w)
-
+    def kernel(px_ref, pw_ref, alpha_ref, gm_ref):
         pw = pw_ref[...]                                 # (bk, w)
-        xor = jnp.bitwise_xor(px[:, None, :], pw[None, :, :])
-        n_neg = jnp.sum(jax.lax.population_count(xor), axis=-1,
-                        dtype=jnp.int32).astype(jnp.float32)       # (B, bk)
-        a = alpha_ref[...]                               # (B, 1)
-        # paper eq. (2), as the exact op sequence core.predictor.margins
-        # lowers to — so the compiled kernel is BITWISE identical to the
-        # jitted multi-dispatch epilogue it replaces (XLA contracts the
-        # mul+sub into an FMA in both; only the un-jitted eager path rounds
-        # the product separately) and selections match the gather strategy.
-        m = n_neg - a * (jnp.float32(d_valid) - n_neg)
-        bk = m.shape[-1]
-        gm = m.reshape(b, bk // group_size, group_size).min(-1)
-        gm_ref[...] = gm                                 # (B, bk/G)
-        cnt_ref[...] += jnp.sum(gm <= 0, axis=-1,
-                                dtype=jnp.int32)[:, None]
+        for t in range(px_ref.shape[0]):
+            n_neg = _token_counts(px_ref, pw, t).astype(jnp.float32)
+            a = alpha_ref[t:t + 1, :]                    # (1, 1)
+            # paper eq. (2), as the exact op sequence core.predictor.margins
+            # lowers to — so the compiled kernel is BITWISE identical to the
+            # jitted multi-dispatch epilogue it replaces (XLA contracts the
+            # mul+sub into an FMA in both; only the un-jitted eager path
+            # rounds the product separately) and selections match the
+            # gather strategy.
+            m = n_neg - a * (jnp.float32(d_valid) - n_neg)   # (1, bk)
+            bk = m.shape[1]
+            gm_ref[t:t + 1, :] = m.reshape(
+                1, bk // group_size, group_size).min(-1)
     return kernel
-
-
-def choose_block_tokens(b: int, max_tokens: int = 128) -> int:
-    """Token-tile for the chunked predictor: largest divisor of ``b`` not
-    exceeding ``max_tokens`` (chunks are MXU-aligned so this is normally
-    just min(b, 128))."""
-    if b <= 0:
-        raise ValueError(f"chunk predictor needs b > 0, got {b}")
-    bt = min(b, max_tokens)
-    while bt > 1 and b % bt:
-        bt -= 1
-    return bt
-
-
-def _make_chunk_group_margins_kernel(d_valid: int, group_size: int):
-    """Token-tiled twin of ``_make_group_margins_kernel`` for prefill
-    chunks (DESIGN.md §9): grid is (token_blocks, k_blocks) with k as the
-    FAST axis, so each count block's revisits are consecutive (TPU output
-    revisit rule) — gm blocks are written exactly once at (i, j), the count
-    block at i accumulates over j.  Same margin op sequence, so selections
-    stay bitwise-aligned with the decode predictor and the jnp oracle.
-    """
-    def kernel(x_ref, pw_ref, alpha_ref, gm_ref, cnt_ref):
-        j = pl.program_id(1)
-
-        @pl.when(j == 0)
-        def _init():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-        x = x_ref[...]                                   # (bt, dp)
-        b, dp = x.shape
-        bits = (x < 0).astype(jnp.uint32)
-        bits = bits.reshape(b, dp // PACK, PACK)
-        weights = jnp.uint32(1) << jnp.arange(PACK, dtype=jnp.uint32)
-        px = jnp.sum(bits * weights, axis=-1,
-                     dtype=jnp.uint32).astype(jnp.int32)  # (bt, w)
-
-        pw = pw_ref[...]                                 # (bk, w)
-        xor = jnp.bitwise_xor(px[:, None, :], pw[None, :, :])
-        n_neg = jnp.sum(jax.lax.population_count(xor), axis=-1,
-                        dtype=jnp.int32).astype(jnp.float32)      # (bt, bk)
-        a = alpha_ref[...]                               # (bt, 1)
-        m = n_neg - a * (jnp.float32(d_valid) - n_neg)
-        bk = m.shape[-1]
-        gm = m.reshape(b, bk // group_size, group_size).min(-1)
-        gm_ref[...] = gm                                 # (bt, bk/G)
-        cnt_ref[...] += jnp.sum(gm <= 0, axis=-1,
-                                dtype=jnp.int32)[:, None]
-    return kernel
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("d_valid", "group_size", "interpret", "block_k",
-                     "block_t"))
-def predict_chunk_group_margins(packed_w: jax.Array,
-                                x: jax.Array,
-                                alpha: jax.Array,
-                                *,
-                                d_valid: int,
-                                group_size: int = 8,
-                                interpret: bool = True,
-                                block_k: int | None = None,
-                                block_t: int | None = None):
-    """Chunked-prefill predictor: same contract as ``predict_group_margins``
-    ((B, k/G) per-row group margins + (B,) predicted counts) but tiled over
-    the token axis as well, so a 64–128-token chunk never blows the VMEM
-    budget that caps the decode kernel's resident batch.
-    """
-    k, w = packed_w.shape
-    b, dp = x.shape
-    assert dp == w * PACK, (dp, w)
-    assert k % group_size == 0, (k, group_size)
-    bt = block_t or choose_block_tokens(b)
-    bk = block_k or choose_block_k(k, w, bt, group_size)
-    grid = (b // bt, k // bk)
-    a = jnp.reshape(alpha.astype(jnp.float32), (b, 1))
-    gm, cnt = pl.pallas_call(
-        _make_chunk_group_margins_kernel(d_valid, group_size),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bt, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, w), lambda i, j: (j, 0)),
-            pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bt, bk // group_size), lambda i, j: (i, j)),
-            pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k // group_size), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(x, packed_w, a)
-    return gm, cnt[:, 0]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("d_valid", "group_size", "interpret", "block_k"))
 def predict_group_margins(packed_w: jax.Array,
-                          x: jax.Array,
+                          packed_x: jax.Array,
                           alpha: jax.Array,
                           *,
                           d_valid: int,
                           group_size: int = 8,
                           interpret: bool = True,
                           block_k: int | None = None):
-    """Single-dispatch decode predictor.
+    """Fused decode / prefill-chunk predictor.
 
-    packed_w: (k, w) int32 packed gate-weight signs; x: (B, w*32) raw input
-    (zero-padded past ``d_valid``); alpha: (B,) per-token conservativeness.
-    Returns ``(gm, cnt)``: per-token per-group margins (B, k/G) float32
-    (group = min over members, ready for batch-union + top-C selection) and
-    per-slot predicted-active group counts (B,) int32.
+    packed_w: (k, w) int32 packed gate-weight signs; packed_x: (B, w) int32
+    packed input signs; alpha: (B,) per-token conservativeness.  Returns
+    ``(gm, cnt)``: per-token per-group margins (B, k/G) float32 (group =
+    min over members, ready for batch-union + top-C selection) and
+    per-token predicted-active group counts (B,) int32.
+
+    The grid is (k tiles, token tiles) with tokens fast, so each packed
+    weight tile is fetched once and stays resident while every token tile
+    of a prefill chunk passes over it.
     """
     k, w = packed_w.shape
-    b, dp = x.shape
-    assert dp == w * PACK, (dp, w)
+    b = packed_x.shape[0]
     assert k % group_size == 0, (k, group_size)
     bk = block_k or choose_block_k(k, w, b, group_size)
-    grid = (k // bk,)
+    bt = min(b, TOKEN_TILE)
     a = jnp.reshape(alpha.astype(jnp.float32), (b, 1))
-    gm, cnt = pl.pallas_call(
+    gm = pl.pallas_call(
         _make_group_margins_kernel(d_valid, group_size),
-        grid=grid,
+        grid=(pl.cdiv(k, bk), pl.cdiv(b, bt)),
         in_specs=[
-            pl.BlockSpec((b, dp), lambda i: (0, 0)),
-            pl.BlockSpec((bk, w), lambda i: (i, 0)),
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
+            pl.BlockSpec((bt, w), lambda i, t: (t, 0)),
+            pl.BlockSpec((bk, w), lambda i, t: (i, 0)),
+            pl.BlockSpec((bt, 1), lambda i, t: (t, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((b, bk // group_size), lambda i: (0, i)),
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
-        ],
-        out_shape=(
-            jax.ShapeDtypeStruct((b, k // group_size), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-        ),
+        out_specs=pl.BlockSpec((bt, bk // group_size), lambda i, t: (t, i)),
+        out_shape=jax.ShapeDtypeStruct((b, k // group_size), jnp.float32),
         interpret=interpret,
-    )(x, packed_w, a)
-    return gm, cnt[:, 0]
+    )(packed_x, packed_w, a)
+    return gm, jnp.sum(gm <= 0, axis=-1, dtype=jnp.int32)

@@ -10,11 +10,6 @@ from repro.core import predictor as P
 from repro.core.relufication import get_activation
 
 
-def sign_pack_ref(v: jax.Array) -> jax.Array:
-    """Oracle for kernels.sign_pack.sign_pack."""
-    return P.pack_signs(v)
-
-
 def predict_counts_ref(packed_w: jax.Array, packed_x: jax.Array) -> jax.Array:
     """Oracle for kernels.predict.predict_counts: (B, k) neg-product counts."""
     return P.neg_counts(packed_w, packed_x)
@@ -23,23 +18,15 @@ def predict_counts_ref(packed_w: jax.Array, packed_x: jax.Array) -> jax.Array:
 def predict_group_margins_ref(packed_w: jax.Array, x: jax.Array,
                               d_valid: int, alpha: jax.Array,
                               group_size: int = 8):
-    """Oracle for kernels.predict.predict_group_margins: the multi-dispatch
-    composition (pack -> margins -> group min) the fused kernel replaces."""
+    """Oracle for ``ops.predict_group_margins``: the multi-dispatch
+    composition (pack -> margins -> group min) the fused kernel replaces.
+    Row tiling never changes per-row results, so it serves decode batches
+    and prefill chunks alike."""
     m = P.margins(packed_w, P.pack_signs(x), d_valid, alpha)     # (B, k)
     b, k = m.shape
     gm = m.reshape(b, k // group_size, group_size).min(-1)       # (B, k/G)
     cnt = jnp.sum(gm <= 0, axis=-1, dtype=jnp.int32)             # (B,)
     return gm, cnt
-
-
-def predict_chunk_group_margins_ref(packed_w: jax.Array, x: jax.Array,
-                                    d_valid: int, alpha: jax.Array,
-                                    group_size: int = 8):
-    """Oracle for kernels.predict.predict_chunk_group_margins: the chunked
-    (token-tiled) predictor computes per-ROW results, so its oracle is the
-    decode predictor's oracle verbatim — the tiling must not change a single
-    bit of any row (DESIGN.md §9)."""
-    return predict_group_margins_ref(packed_w, x, d_valid, alpha, group_size)
 
 
 def fused_mlp_telemetry_ref(x: jax.Array,
@@ -129,7 +116,7 @@ def fused_sparse_mlp_chunk_ref(x: jax.Array,
                                activation: str = "relu",
                                fatrelu_threshold: float = 0.0,
                                collect_stats: bool = False):
-    """Oracle for kernels.sparse_mlp_fused.fused_sparse_mlp_chunk: per-row
+    """Oracle for ``ops.fused_sparse_mlp_chunk``: per-row
     math is row-tiling-invariant, so it composes the untiled MLP oracle with
     the telemetry oracle (matching the chunked kernel's (y, tel) contract
     when ``collect_stats``)."""
@@ -232,9 +219,8 @@ def fused_sparse_mlp_q_ref(x: jax.Array,
 
 
 def fused_sparse_mlp_chunk_q_ref(*args, **kw):
-    """Oracle for kernels.sparse_mlp_fused.fused_sparse_mlp_chunk_q: row
-    tiling never changes per-row math, so the decode oracle IS the chunk
-    oracle (same argument as :func:`predict_chunk_group_margins_ref`)."""
+    """Oracle for ``ops.fused_sparse_mlp_chunk_q``: row tiling never
+    changes per-row math, so the decode oracle IS the chunk oracle."""
     return fused_sparse_mlp_q_ref(*args, **kw)
 
 

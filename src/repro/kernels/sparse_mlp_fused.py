@@ -19,9 +19,9 @@ group 0 harmlessly (capacity slack is a DSE knob, DESIGN.md §2).
 
 In-kernel telemetry (``collect_stats=True``, DESIGN.md §4): alongside the
 accumulator the kernel folds three per-token int32 counters over the grid —
-``TELEMETRY_COLS = (actual, false_neg, realized)`` — by also prefetching the
-token's own group margin for the step's group (a (B, 1) DMA driven by the
-same scalar-prefetched index).  ``actual`` counts computed rows whose gate
+``TELEMETRY_COLS = (actual, false_neg, realized)`` — reading the token's own
+group margin for the step's group out of a resident (B, C) block of the
+margins in selection order.  ``actual`` counts computed rows whose gate
 fired (paper's realized gate activity), ``false_neg`` is the in-union
 false-negative proxy (gate fired but THIS token's margin said skip — rows it
 only got because a co-resident token kept them), ``realized`` counts the
@@ -57,70 +57,6 @@ def _telemetry_delta(ga, keep):
     ], axis=-1)
 
 
-def _make_kernel(activation: str, fatrelu_threshold: float, gated: bool,
-                 collect_stats: bool, groups_per_step: int = 1,
-                 sel_axis: int = 0):
-    """``sel_axis``: which grid axis walks the selection.  The decode kernel
-    uses a 1-D grid (axis 0); the chunked-prefill kernel adds a slow
-    row-block axis in front and walks the selection on axis 1, so each row
-    block's accumulator sees i==0 (init) at its first visit and the
-    accumulation order over selected groups is identical to the decode
-    kernel's — per-row results are bitwise-equal across the two tilings."""
-    act = get_activation(
-        "fatrelu" if (activation == "fatrelu" or fatrelu_threshold > 0.0)
-        else activation, fatrelu_threshold)
-    per = (3 if gated else 2) + (1 if collect_stats else 0)
-
-    def kernel(sel_ref, cnt_ref, *refs):
-        x_ref = refs[0]
-        tiles = refs[1:1 + groups_per_step * per]
-        rest = refs[1 + groups_per_step * per:]
-        if collect_stats:
-            y_ref, tel_ref = rest
-        else:
-            (y_ref,) = rest
-            tel_ref = None
-        i = pl.program_id(sel_axis)
-
-        @pl.when(i == 0)
-        def _init():
-            y_ref[...] = jnp.zeros_like(y_ref)
-            if collect_stats:
-                tel_ref[...] = jnp.zeros_like(tel_ref)
-
-        # sequential sub-steps over the tile's groups_per_step selected
-        # groups: the accumulation order is identical to the one-group-per-
-        # step grid, so per-bucket tiling never changes results (bitwise)
-        for j in range(groups_per_step):
-            base = j * per
-            wg_ref = tiles[base]
-            wu_ref = tiles[base + 1] if gated else None
-            wd_ref = tiles[base + (2 if gated else 1)]
-            gm_ref = tiles[base + per - 1] if collect_stats else None
-
-            @pl.when(i * groups_per_step + j < cnt_ref[0])
-            def _step(wg_ref=wg_ref, wu_ref=wu_ref, wd_ref=wd_ref,
-                      gm_ref=gm_ref):
-                x = x_ref[...]                               # (B, d)
-                g = jax.lax.dot_general(
-                    x, wg_ref[...], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # (B, G)
-                ga = act(g)
-                if wu_ref is not None:
-                    u = jax.lax.dot_general(
-                        x, wu_ref[...], (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                    h = ga * u                               # (B, G)
-                else:
-                    h = ga
-                y_ref[...] += jax.lax.dot_general(
-                    h.astype(x.dtype), wd_ref[...], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # (B, d)
-                if collect_stats:
-                    tel_ref[...] += _telemetry_delta(ga, gm_ref[...] <= 0)
-    return kernel
-
-
 def _qdot(x, wq, s, qgs: int):
     """Quantized row-group dot with epilogue dequant (DESIGN.md §13):
     ``sum_q (x[:, q] @ wq[:, q]ᵀ) * s[:, q]`` accumulated in ascending
@@ -140,30 +76,40 @@ def _qdot(x, wq, s, qgs: int):
     return acc
 
 
-def _make_kernel_q(activation: str, fatrelu_threshold: float, gated: bool,
-                   collect_stats: bool, groups_per_step: int = 1,
-                   sel_axis: int = 0, qgs: int = 128):
-    """int8-weight twin of :func:`_make_kernel`: per sub-step the weight
-    tiles arrive as int8 + their fp scale tiles, and dequant folds into the
-    accumulator epilogue — gate/up via :func:`_qdot`, down-proj as a pure
-    ``(h @ Wq) * s_row`` multiply (the selection tile lies inside one quant
-    row-group, so one (1, d) scale row covers it).  Telemetry is the
-    UNCHANGED :func:`_telemetry_delta` fold over the (quantized) gate."""
+def _make_kernel(activation: str, fatrelu_threshold: float, gated: bool,
+                 collect_stats: bool, groups_per_step: int, qgs: int,
+                 per: int):
+    """Kernel body for the 2-D grid (row_blocks, cap/gps): the selection
+    walks grid axis 1 (the fast axis), so each row block's accumulator sees
+    i==0 (init) at its first visit and folds the selected groups in
+    selection order — per-row results do not depend on the row tiling.
+    ``per`` is the number of tile operands of one selected group.
+
+    ``qgs`` > 0 selects the int8 path (DESIGN.md §13): each weight tile
+    arrives with its f32 scale tile, gate/up dequantize via :func:`_qdot`,
+    and the down-proj dequant is a pure ``(h @ Wq) * s_row`` epilogue.  The
+    (k/qg, d) down-proj scale matrix is one resident block; the step reads
+    the row covering its selection group (``qg % G == 0`` pins the group
+    inside one quant row-group).
+
+    With ``collect_stats`` the token's own margin for the step's group is
+    picked out of a resident (rows, cap) block of selection-ordered margins
+    (a lane select over the capacity — exact, and legal on the TPU where a
+    one-lane (rows, 1) block of the (rows, k/G) margins is not)."""
     act = get_activation(
         "fatrelu" if (activation == "fatrelu" or fatrelu_threshold > 0.0)
         else activation, fatrelu_threshold)
-    per = 2 * (3 if gated else 2) + (1 if collect_stats else 0)
+    quant = qgs > 0
 
     def kernel(sel_ref, cnt_ref, *refs):
         x_ref = refs[0]
         tiles = refs[1:1 + groups_per_step * per]
-        rest = refs[1 + groups_per_step * per:]
-        if collect_stats:
-            y_ref, tel_ref = rest
-        else:
-            (y_ref,) = rest
-            tel_ref = None
-        i = pl.program_id(sel_axis)
+        rest = list(refs[1 + groups_per_step * per:])
+        wds_ref = rest.pop(0) if quant else None
+        gm_ref = rest.pop(0) if collect_stats else None
+        y_ref = rest.pop(0)
+        tel_ref = rest.pop(0) if collect_stats else None
+        i = pl.program_id(1)
 
         @pl.when(i == 0)
         def _init():
@@ -171,32 +117,50 @@ def _make_kernel_q(activation: str, fatrelu_threshold: float, gated: bool,
             if collect_stats:
                 tel_ref[...] = jnp.zeros_like(tel_ref)
 
+        # sequential sub-steps over the tile's groups_per_step selected
+        # groups: the accumulation order is identical to the one-group-per-
+        # step grid, so per-bucket tiling never changes results (bitwise)
         for j in range(groups_per_step):
-            base = j * per
-            wgq_ref, wgs_ref = tiles[base], tiles[base + 1]
-            wuq_ref = tiles[base + 2] if gated else None
-            wus_ref = tiles[base + 3] if gated else None
-            off = 4 if gated else 2
-            wdq_ref, wds_ref = tiles[base + off], tiles[base + off + 1]
-            gm_ref = tiles[base + per - 1] if collect_stats else None
+            step = tiles[j * per:(j + 1) * per]
+            n = i * groups_per_step + j
 
-            @pl.when(i * groups_per_step + j < cnt_ref[0])
-            def _step(wgq_ref=wgq_ref, wgs_ref=wgs_ref, wuq_ref=wuq_ref,
-                      wus_ref=wus_ref, wdq_ref=wdq_ref, wds_ref=wds_ref,
-                      gm_ref=gm_ref):
-                x = x_ref[...].astype(jnp.float32)           # (B, d)
-                ga = act(_qdot(x, wgq_ref[...], wgs_ref[...], qgs))
-                if wuq_ref is not None:
-                    h = ga * _qdot(x, wuq_ref[...], wus_ref[...], qgs)
+            @pl.when(n < cnt_ref[0])
+            def _step(step=step, n=n):
+                if quant:
+                    wgq, wgs = step[0], step[1]
+                    x = x_ref[...].astype(jnp.float32)       # (B, d)
+                    ga = act(_qdot(x, wgq[...], wgs[...], qgs))
+                    h = (ga * _qdot(x, step[2][...], step[3][...], qgs)
+                         if gated else ga)
+                    yd = jax.lax.dot_general(
+                        h, step[-1][...].astype(jnp.float32),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # (B, d)
+                    row = sel_ref[n] // (qgs // step[0].shape[0])
+                    y_ref[...] += yd * wds_ref[pl.ds(row, 1), :]
                 else:
-                    h = ga
-                yd = jax.lax.dot_general(
-                    h, wdq_ref[...].astype(jnp.float32),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)      # (B, d)
-                y_ref[...] += yd * wds_ref[...]              # epilogue scale
+                    x = x_ref[...]                           # (B, d)
+                    g = jax.lax.dot_general(
+                        x, step[0][...], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # (B, G)
+                    ga = act(g)
+                    if gated:
+                        u = jax.lax.dot_general(
+                            x, step[1][...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        h = ga * u                           # (B, G)
+                    else:
+                        h = ga
+                    y_ref[...] += jax.lax.dot_general(
+                        h.astype(x.dtype), step[-1][...],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # (B, d)
                 if collect_stats:
-                    tel_ref[...] += _telemetry_delta(ga, gm_ref[...] <= 0)
+                    gm = gm_ref[...]                         # (B, cap)
+                    lane = jax.lax.broadcasted_iota(jnp.int32, gm.shape, 1)
+                    own = jnp.min(jnp.where(lane == n, gm, jnp.inf),
+                                  axis=-1, keepdims=True)    # (B, 1)
+                    tel_ref[...] += _telemetry_delta(ga, own <= 0)
     return kernel
 
 
@@ -214,10 +178,98 @@ def mlp_groups_per_step(cap_groups: int, group_size: int) -> int:
     return 1
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "activation", "fatrelu_threshold",
-                     "collect_stats", "interpret", "groups_per_step"))
+def choose_block_rows(b: int, d: int, max_vmem: int = 4 * 1024 * 1024) -> int:
+    """Row-block height for a prefill chunk: largest divisor of ``b`` whose
+    (bt, d) f32 accumulator stays under ~``max_vmem``.  Raises
+    ``ValueError`` when that divisor is neither the whole chunk nor a
+    multiple of 8 sublanes (the TPU block-shape rule)."""
+    if b <= 0 or d <= 0:
+        raise ValueError(f"chunk MLP tiling needs b,d > 0, got b={b} d={d}")
+    budget = max(1, max_vmem // (4 * d))
+    bt = min(b, budget, 128)
+    while bt > 1 and b % bt:
+        bt -= 1
+    if bt != b and bt % 8:
+        raise ValueError(
+            f"chunk MLP rows b={b} at d={d}: largest row block under the "
+            f"VMEM budget is {bt}, not a multiple of 8")
+    return bt
+
+
+def _fused_call(x, weights, wd_s, sel_indices, sel_count, gm_tok, *,
+                group_size, activation, fatrelu_threshold, collect_stats,
+                interpret, groups_per_step, block_rows):
+    """Build and run the fused pallas_call.  ``weights`` is the per-step
+    tile operand list of one selected group — ``(wg, wu, wd)`` (fp) or
+    ``(wg_q, wg_s, wu_q, wu_s, wd_q)`` (int8, with ``wd_s`` given); ungated
+    MLPs drop the up-projection entries."""
+    b, d = x.shape
+    k = weights[0].shape[0]
+    g = group_size
+    assert k % g == 0
+    cap = sel_indices.shape[0]
+    quant = wd_s is not None
+    qgs = d // weights[1].shape[1] if quant else 0
+    if collect_stats:
+        assert gm_tok is not None and gm_tok.shape == (b, k // g), (
+            "collect_stats needs per-token group margins (B, k/G)")
+    gps = groups_per_step or mlp_groups_per_step(cap, g)
+    if cap % gps:
+        raise ValueError(
+            f"groups_per_step={gps} must divide the selection capacity "
+            f"{cap} (per-bucket tiling, DESIGN.md §2)")
+    bt = block_rows or b
+    if b % bt:
+        raise ValueError(f"block_rows={bt} must divide the rows {b}")
+
+    cnt = jnp.reshape(sel_count.astype(jnp.int32), (1,))
+    in_specs = [pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))]
+    operands = [x]
+    for j in range(gps):
+        for w in weights:
+            in_specs.append(pl.BlockSpec(
+                (g, w.shape[1]),
+                lambda r, i, sel, cnt, j=j: (sel[i * gps + j], 0)))
+            operands.append(w)
+    if quant:
+        in_specs.append(pl.BlockSpec(wd_s.shape,
+                                     lambda r, i, sel, cnt: (0, 0)))
+        operands.append(wd_s)
+    if collect_stats:
+        in_specs.append(pl.BlockSpec((bt, cap),
+                                     lambda r, i, sel, cnt: (r, 0)))
+        operands.append(jnp.take(gm_tok.astype(jnp.float32), sel_indices,
+                                 axis=1))
+    out_specs = pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))
+    out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
+    if collect_stats:
+        ncol = len(TELEMETRY_COLS)
+        out_specs = [out_specs,
+                     pl.BlockSpec((bt, ncol), lambda r, i, sel, cnt: (r, 0))]
+        out_shape = (out_shape, jax.ShapeDtypeStruct((b, ncol), jnp.int32))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b // bt, cap // gps),
+        in_specs=in_specs,
+        out_specs=out_specs,
+    )
+    gated = len(weights) == (5 if quant else 3)
+    kernel = _make_kernel(activation, fatrelu_threshold, gated,
+                          collect_stats, gps, qgs, len(weights))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(sel_indices.astype(jnp.int32), cnt, *operands)
+
+
+_STATIC = ("group_size", "activation", "fatrelu_threshold", "collect_stats",
+           "interpret", "groups_per_step", "block_rows")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_sparse_mlp(x: jax.Array,
                      wg_t: jax.Array,
                      wu_t: jax.Array | None,
@@ -231,7 +283,8 @@ def fused_sparse_mlp(x: jax.Array,
                      fatrelu_threshold: float = 0.0,
                      collect_stats: bool = False,
                      interpret: bool = True,
-                     groups_per_step: int = 0):
+                     groups_per_step: int = 0,
+                     block_rows: int = 0):
     """x: (B, d); w*_t: (k, d) neuron-major; sel_indices: (C,) group ids.
 
     Returns y: (B, d) float32 (one fused HBM pass over selected groups).
@@ -242,73 +295,22 @@ def fused_sparse_mlp(x: jax.Array,
     ``groups_per_step`` (0 = auto via :func:`mlp_groups_per_step`) is the
     per-bucket weight-tile height: each grid step scalar-prefetches that
     many selected groups of every matrix, so wide capacity buckets get a
-    taller effective tile.  Results are bitwise-independent of the choice
-    (the sub-steps accumulate in selection order).
+    taller effective tile.  ``block_rows`` (0 = all rows, the decode case)
+    tiles the rows of a prefill chunk (DESIGN.md §9): one chunk-union
+    selection drives the weight DMAs of every row block.  Results are
+    bitwise-independent of both (the sub-steps accumulate in selection
+    order).
     """
-    b, d = x.shape
-    k = wg_t.shape[0]
-    g = group_size
-    assert k % g == 0
-    cap = sel_indices.shape[0]
-    gated = wu_t is not None
-    if collect_stats:
-        assert gm_tok is not None and gm_tok.shape == (b, k // g), (
-            "collect_stats needs per-token group margins (B, k/G)")
-    gps = groups_per_step or mlp_groups_per_step(cap, g)
-    if cap % gps:
-        raise ValueError(
-            f"groups_per_step={gps} must divide the selection capacity "
-            f"{cap} (per-bucket tiling, DESIGN.md §2)")
-
-    cnt = jnp.reshape(sel_count.astype(jnp.int32), (1,))
-    in_specs = [pl.BlockSpec((b, d), lambda i, sel, cnt: (0, 0))]
-    operands = [x]
-    for j in range(gps):
-        w_spec = pl.BlockSpec(
-            (g, d), lambda i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        in_specs.append(w_spec)
-        operands.append(wg_t)
-        if gated:
-            in_specs.append(w_spec)
-            operands.append(wu_t)
-        in_specs.append(w_spec)
-        operands.append(wd_t)
-        if collect_stats:
-            # the sub-step's own-margin column rides the same prefetched
-            # index
-            in_specs.append(pl.BlockSpec(
-                (b, 1), lambda i, sel, cnt, j=j: (0, sel[i * gps + j])))
-            operands.append(gm_tok.astype(jnp.float32))
-    out_specs = pl.BlockSpec((b, d), lambda i, sel, cnt: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
-    if collect_stats:
-        out_specs = [out_specs,
-                     pl.BlockSpec((b, len(TELEMETRY_COLS)),
-                                  lambda i, sel, cnt: (0, 0))]
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b, len(TELEMETRY_COLS)),
-                                          jnp.int32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(cap // gps,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
-    kernel = _make_kernel(activation, fatrelu_threshold, gated,
-                          collect_stats, gps)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(sel_indices.astype(jnp.int32), cnt, *operands)
+    weights = (wg_t, wd_t) if wu_t is None else (wg_t, wu_t, wd_t)
+    return _fused_call(
+        x, weights, None, sel_indices, sel_count, gm_tok,
+        group_size=group_size, activation=activation,
+        fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats,
+        interpret=interpret, groups_per_step=groups_per_step,
+        block_rows=block_rows)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "activation", "fatrelu_threshold",
-                     "collect_stats", "interpret", "groups_per_step"))
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def fused_sparse_mlp_q(x: jax.Array,
                        wg_q: jax.Array,
                        wg_s: jax.Array,
@@ -325,18 +327,18 @@ def fused_sparse_mlp_q(x: jax.Array,
                        fatrelu_threshold: float = 0.0,
                        collect_stats: bool = False,
                        interpret: bool = True,
-                       groups_per_step: int = 0):
+                       groups_per_step: int = 0,
+                       block_rows: int = 0):
     """int8-weight twin of :func:`fused_sparse_mlp` (DESIGN.md §13).
 
     ``w*_q``: int8 (k, d) neuron-major; ``wg_s``/``wu_s``: f32 (k, d/qg)
     row-grouped scales; ``wd_s``: f32 (k/qg, d) column-grouped scales.
-    Each grid step DMAs the selected int8 row-groups PLUS their scale
-    tiles — the wd scale tile is the single (1, d) row covering the
-    selection group (``qg % group_size == 0`` pins it to one row-group).
-    Dequant happens in the accumulator epilogue; HBM weight traffic is
-    ~1 byte/elt + the thin scale stream (see :func:`kernel_hbm_bytes`).
+    Each grid step DMAs the selected int8 row-groups PLUS their gate/up
+    scale tiles; the down-proj scales stay resident.  Dequant happens in
+    the accumulator epilogue; HBM weight traffic is ~1 byte/elt + the thin
+    scale stream (see :func:`kernel_hbm_bytes`).
     """
-    b, d = x.shape
+    d = x.shape[1]
     k = wg_q.shape[0]
     g = group_size
     nq = wg_s.shape[1]
@@ -344,262 +346,14 @@ def fused_sparse_mlp_q(x: jax.Array,
     qg = d // nq
     assert k % g == 0 and qg % g == 0 and k % qg == 0, (
         f"bad quant tiling: k={k} d={d} g={g} qg={qg} (DESIGN.md §13)")
-    qpg = qg // g                       # selection groups per quant row-group
-    cap = sel_indices.shape[0]
-    gated = wu_q is not None
-    if collect_stats:
-        assert gm_tok is not None and gm_tok.shape == (b, k // g), (
-            "collect_stats needs per-token group margins (B, k/G)")
-    gps = groups_per_step or mlp_groups_per_step(cap, g)
-    if cap % gps:
-        raise ValueError(
-            f"groups_per_step={gps} must divide the selection capacity "
-            f"{cap} (per-bucket tiling, DESIGN.md §2)")
-
-    cnt = jnp.reshape(sel_count.astype(jnp.int32), (1,))
-    in_specs = [pl.BlockSpec((b, d), lambda i, sel, cnt: (0, 0))]
-    operands = [x]
-    for j in range(gps):
-        w_spec = pl.BlockSpec(
-            (g, d), lambda i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        s_spec = pl.BlockSpec(
-            (g, nq), lambda i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        in_specs += [w_spec, s_spec]
-        operands += [wg_q, wg_s]
-        if gated:
-            in_specs += [w_spec, s_spec]
-            operands += [wu_q, wu_s]
-        in_specs += [w_spec, pl.BlockSpec(
-            (1, d), lambda i, sel, cnt, j=j: (sel[i * gps + j] // qpg, 0))]
-        operands += [wd_q, wd_s]
-        if collect_stats:
-            in_specs.append(pl.BlockSpec(
-                (b, 1), lambda i, sel, cnt, j=j: (0, sel[i * gps + j])))
-            operands.append(gm_tok.astype(jnp.float32))
-    out_specs = pl.BlockSpec((b, d), lambda i, sel, cnt: (0, 0))
-    out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
-    if collect_stats:
-        out_specs = [out_specs,
-                     pl.BlockSpec((b, len(TELEMETRY_COLS)),
-                                  lambda i, sel, cnt: (0, 0))]
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b, len(TELEMETRY_COLS)),
-                                          jnp.int32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(cap // gps,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
-    kernel = _make_kernel_q(activation, fatrelu_threshold, gated,
-                            collect_stats, gps, qgs=qg)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(sel_indices.astype(jnp.int32), cnt, *operands)
-
-
-def choose_block_rows(b: int, d: int, max_vmem: int = 4 * 1024 * 1024) -> int:
-    """Row-block height for the chunked fused MLP: largest divisor of ``b``
-    whose (bt, d) f32 accumulator stays under ~``max_vmem``."""
-    if b <= 0 or d <= 0:
-        raise ValueError(f"chunk MLP tiling needs b,d > 0, got b={b} d={d}")
-    budget = max(1, max_vmem // (4 * d))
-    bt = min(b, budget, 128)
-    while bt > 1 and b % bt:
-        bt -= 1
-    return bt
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "activation", "fatrelu_threshold",
-                     "collect_stats", "interpret", "groups_per_step",
-                     "block_rows"))
-def fused_sparse_mlp_chunk(x: jax.Array,
-                           wg_t: jax.Array,
-                           wu_t: jax.Array | None,
-                           wd_t: jax.Array,
-                           sel_indices: jax.Array,
-                           sel_count: jax.Array,
-                           gm_tok: jax.Array | None = None,
-                           *,
-                           group_size: int = 8,
-                           activation: str = "relu",
-                           fatrelu_threshold: float = 0.0,
-                           collect_stats: bool = False,
-                           interpret: bool = True,
-                           groups_per_step: int = 0,
-                           block_rows: int = 0):
-    """Row-tiled twin of :func:`fused_sparse_mlp` for prefill chunks
-    (DESIGN.md §9): grid (row_blocks, cap/gps) with the SELECTION as the
-    fast axis, so each row block's accumulator initializes once and folds
-    the selected groups in the same order as the decode kernel — per-row
-    outputs and telemetry are bitwise-equal to the untiled kernel.  One
-    chunk-union selection (the caller unions margins over the chunk) drives
-    the weight DMAs for every row block, so selected weights stream once
-    per row block instead of once per token.
-    """
-    b, d = x.shape
-    k = wg_t.shape[0]
-    g = group_size
-    assert k % g == 0
-    cap = sel_indices.shape[0]
-    gated = wu_t is not None
-    if collect_stats:
-        assert gm_tok is not None and gm_tok.shape == (b, k // g), (
-            "collect_stats needs per-token group margins (B, k/G)")
-    gps = groups_per_step or mlp_groups_per_step(cap, g)
-    if cap % gps:
-        raise ValueError(
-            f"groups_per_step={gps} must divide the selection capacity "
-            f"{cap} (per-bucket tiling, DESIGN.md §2)")
-    bt = block_rows or choose_block_rows(b, d)
-    if b % bt:
-        raise ValueError(f"block_rows={bt} must divide the chunk rows {b}")
-
-    cnt = jnp.reshape(sel_count.astype(jnp.int32), (1,))
-    in_specs = [pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))]
-    operands = [x]
-    for j in range(gps):
-        w_spec = pl.BlockSpec(
-            (g, d), lambda r, i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        in_specs.append(w_spec)
-        operands.append(wg_t)
-        if gated:
-            in_specs.append(w_spec)
-            operands.append(wu_t)
-        in_specs.append(w_spec)
-        operands.append(wd_t)
-        if collect_stats:
-            in_specs.append(pl.BlockSpec(
-                (bt, 1), lambda r, i, sel, cnt, j=j: (r, sel[i * gps + j])))
-            operands.append(gm_tok.astype(jnp.float32))
-    out_specs = pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))
-    out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
-    if collect_stats:
-        out_specs = [out_specs,
-                     pl.BlockSpec((bt, len(TELEMETRY_COLS)),
-                                  lambda r, i, sel, cnt: (r, 0))]
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b, len(TELEMETRY_COLS)),
-                                          jnp.int32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b // bt, cap // gps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
-    kernel = _make_kernel(activation, fatrelu_threshold, gated,
-                          collect_stats, gps, sel_axis=1)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(sel_indices.astype(jnp.int32), cnt, *operands)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "activation", "fatrelu_threshold",
-                     "collect_stats", "interpret", "groups_per_step",
-                     "block_rows"))
-def fused_sparse_mlp_chunk_q(x: jax.Array,
-                             wg_q: jax.Array,
-                             wg_s: jax.Array,
-                             wu_q: jax.Array | None,
-                             wu_s: jax.Array | None,
-                             wd_q: jax.Array,
-                             wd_s: jax.Array,
-                             sel_indices: jax.Array,
-                             sel_count: jax.Array,
-                             gm_tok: jax.Array | None = None,
-                             *,
-                             group_size: int = 8,
-                             activation: str = "relu",
-                             fatrelu_threshold: float = 0.0,
-                             collect_stats: bool = False,
-                             interpret: bool = True,
-                             groups_per_step: int = 0,
-                             block_rows: int = 0):
-    """Row-tiled int8 twin of :func:`fused_sparse_mlp_chunk` (DESIGN.md
-    §9/§13): grid (row_blocks, cap/gps), selection on the fast axis, int8
-    tiles + scale tiles DMA'd per selected group exactly as in
-    :func:`fused_sparse_mlp_q` — per-row results bitwise-equal to it."""
-    b, d = x.shape
-    k = wg_q.shape[0]
-    g = group_size
-    nq = wg_s.shape[1]
-    assert d % nq == 0
-    qg = d // nq
-    assert k % g == 0 and qg % g == 0 and k % qg == 0, (
-        f"bad quant tiling: k={k} d={d} g={g} qg={qg} (DESIGN.md §13)")
-    qpg = qg // g
-    cap = sel_indices.shape[0]
-    gated = wu_q is not None
-    if collect_stats:
-        assert gm_tok is not None and gm_tok.shape == (b, k // g), (
-            "collect_stats needs per-token group margins (B, k/G)")
-    gps = groups_per_step or mlp_groups_per_step(cap, g)
-    if cap % gps:
-        raise ValueError(
-            f"groups_per_step={gps} must divide the selection capacity "
-            f"{cap} (per-bucket tiling, DESIGN.md §2)")
-    bt = block_rows or choose_block_rows(b, d)
-    if b % bt:
-        raise ValueError(f"block_rows={bt} must divide the chunk rows {b}")
-
-    cnt = jnp.reshape(sel_count.astype(jnp.int32), (1,))
-    in_specs = [pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))]
-    operands = [x]
-    for j in range(gps):
-        w_spec = pl.BlockSpec(
-            (g, d), lambda r, i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        s_spec = pl.BlockSpec(
-            (g, nq), lambda r, i, sel, cnt, j=j: (sel[i * gps + j], 0))
-        in_specs += [w_spec, s_spec]
-        operands += [wg_q, wg_s]
-        if gated:
-            in_specs += [w_spec, s_spec]
-            operands += [wu_q, wu_s]
-        in_specs += [w_spec, pl.BlockSpec(
-            (1, d),
-            lambda r, i, sel, cnt, j=j: (sel[i * gps + j] // qpg, 0))]
-        operands += [wd_q, wd_s]
-        if collect_stats:
-            in_specs.append(pl.BlockSpec(
-                (bt, 1),
-                lambda r, i, sel, cnt, j=j: (r, sel[i * gps + j])))
-            operands.append(gm_tok.astype(jnp.float32))
-    out_specs = pl.BlockSpec((bt, d), lambda r, i, sel, cnt: (r, 0))
-    out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
-    if collect_stats:
-        out_specs = [out_specs,
-                     pl.BlockSpec((bt, len(TELEMETRY_COLS)),
-                                  lambda r, i, sel, cnt: (r, 0))]
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((b, len(TELEMETRY_COLS)),
-                                          jnp.int32))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b // bt, cap // gps),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
-    kernel = _make_kernel_q(activation, fatrelu_threshold, gated,
-                            collect_stats, gps, sel_axis=1, qgs=qg)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(sel_indices.astype(jnp.int32), cnt, *operands)
+    weights = ((wg_q, wg_s, wd_q) if wu_q is None
+               else (wg_q, wg_s, wu_q, wu_s, wd_q))
+    return _fused_call(
+        x, weights, wd_s, sel_indices, sel_count, gm_tok,
+        group_size=group_size, activation=activation,
+        fatrelu_threshold=fatrelu_threshold, collect_stats=collect_stats,
+        interpret=interpret, groups_per_step=groups_per_step,
+        block_rows=block_rows)
 
 
 def kernel_hbm_bytes(b: int, d: int, k: int, cap_groups: int, group_size: int,
@@ -618,8 +372,8 @@ def kernel_hbm_bytes(b: int, d: int, k: int, cap_groups: int, group_size: int,
 
     Weight traffic is itemized per weight dtype (DESIGN.md §13):
     ``weight_dtype="int8"`` streams 1-byte tiles plus the f32 scale vectors
-    (row-grouped ``(rows, d/qg)`` for gate/up, one ``(1, d)`` row per
-    selected group for down-proj); activation traffic uses ``act_bytes``
+    (row-grouped ``(rows, d/qg)`` for gate/up per selected group, the
+    resident ``(k/qg, d)`` down-proj matrix once per call); activation traffic uses ``act_bytes``
     (defaults to ``weight_bytes`` for back-compat with the fp model, where
     weights and activations share a dtype).
     """
@@ -636,10 +390,10 @@ def kernel_hbm_bytes(b: int, d: int, k: int, cap_groups: int, group_size: int,
         dense_w = n_mats * k * d
         dense_s = n_row_mats * k * (d // qg) * 4 + (k // qg) * d * 4
         fused_w = n_mats * sel_rows * d
-        # per selected group: (G, d/qg) gate/up scale tiles + ONE (1, d)
-        # down-proj scale row (qg % G == 0 pins the tile to a row-group)
+        # per selected group: (G, d/qg) gate/up scale tiles; the whole
+        # (k/qg, d) down-proj scale matrix is read once (resident block)
         fused_s = (n_row_mats * sel_rows * (d // qg) * 4
-                   + cap_groups * d * 4)
+                   + (k // qg) * d * 4)
     else:
         dense_w = n_mats * k * d * weight_bytes
         dense_s = 0

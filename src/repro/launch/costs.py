@@ -25,14 +25,6 @@ import jax
 import numpy as np
 
 
-def normalize_cost_analysis(cost) -> dict:
-    """``compiled.cost_analysis()`` returns ``[dict]`` on jax<=0.4.x and a
-    bare dict on newer releases; give callers the dict either way."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 # ------------------------------------------------------------ jaxpr walk --
 
 def _aval_bytes(aval) -> int:

@@ -134,8 +134,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
         colls = parse_collectives(hlo_text)
         # trip-count-aware models (XLA counts while bodies once — see costs.py)
         from repro.launch.costs import (collectives_with_trip_counts,
-                                        jaxpr_cost, normalize_cost_analysis)
-        cost = normalize_cost_analysis(compiled.cost_analysis())
+                                        jaxpr_cost)
+        cost = compiled.cost_analysis()
         colls_tc = collectives_with_trip_counts(hlo_text)
         jcost = jaxpr_cost(step, *args)
 

@@ -16,13 +16,15 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs.base import (ControllerConfig, MetricsConfig,
-                                PagedKVConfig)
+                                ModelConfig, PagedKVConfig)
 from repro.configs.registry import arch_names, get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.specs import model_module
 from repro.launch.train import parse_mesh
@@ -51,7 +53,7 @@ def parse_sla_mix(mix: str, n_requests: int) -> list:
     return out
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=arch_names())
     ap.add_argument("--reduced", action="store_true")
@@ -189,8 +191,11 @@ def main() -> None:
     ap.add_argument("--metrics-snapshot", default="", metavar="PATH",
                     help="write a Prometheus-style text exposition of all "
                          "instruments at each serve drain")
-    args = ap.parse_args()
+    return ap
 
+
+def build_config(args: argparse.Namespace) -> ModelConfig:
+    """The served model configuration the flags select."""
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if args.strategy:
         sp = dataclasses.replace(cfg.sparse, strategy=args.strategy,
@@ -215,6 +220,75 @@ def main() -> None:
             cfg.sparse, sparse_prefill=True,
             prefill_max_tokens=max(cfg.sparse.prefill_max_tokens,
                                    args.prefill_chunk)))
+    return cfg
+
+
+def init_params(mod, cfg: ModelConfig, seed: int = 0) -> dict:
+    """Random weights from ``seed``, initialized under one jit so that a
+    full-size model never holds float32 temporaries of every stacked
+    layer on the device at once."""
+    return jax.jit(mod.init_lm, static_argnums=1)(jax.random.PRNGKey(seed),
+                                                  cfg)
+
+
+def build_server(args: argparse.Namespace, cfg: ModelConfig, mod,
+                 params: dict, rng: np.random.Generator,
+                 mesh: Optional[jax.sharding.Mesh] = None) -> Server:
+    """The Server the flags describe, over ``params`` (stub frontend
+    inputs for the vlm / encdec families are drawn from ``rng``)."""
+    extra = {}
+    if cfg.family == "vlm":
+        extra["images"] = jax.numpy.asarray(rng.standard_normal(
+            (args.batch, cfg.n_image_tokens, cfg.d_model),
+            dtype=np.float32))
+    if cfg.family == "encdec":
+        extra["frames"] = jax.numpy.asarray(rng.standard_normal(
+            (args.batch, cfg.n_frames, cfg.d_model), dtype=np.float32))
+    ccfg = ControllerConfig(enabled=args.controller,
+                            target_density=args.target_density,
+                            gain=args.ctrl_gain,
+                            audit_period=args.audit_period,
+                            adapt_capacity=args.adapt_capacity,
+                            per_tier=args.per_tier)
+    paged = (PagedKVConfig(block_size=args.paged_kv,
+                           pool_blocks=args.pool_blocks)
+             if args.paged_kv else None)
+    mcfg = MetricsConfig(
+        enabled=bool(args.metrics or args.metrics_jsonl
+                     or args.metrics_trace or args.metrics_snapshot),
+        jsonl_path=args.metrics_jsonl,
+        trace=bool(args.metrics_trace),
+        trace_path=args.metrics_trace,
+        snapshot_path=args.metrics_snapshot)
+    scfg = ServeConfig(batch=args.batch, max_len=args.max_len,
+                       max_new_tokens=args.max_new,
+                       slot_refill=args.slot_refill, controller=ccfg,
+                       warm_buckets=args.warm_buckets,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_interleave=args.prefill_interleave,
+                       controller_ckpt=args.controller_ckpt, paged_kv=paged,
+                       max_queue_depth=args.max_queue_depth,
+                       default_deadline_s=args.default_deadline,
+                       preempt=args.preempt,
+                       pressure_gate=args.pressure_gate, metrics=mcfg)
+    return Server(mod, cfg, scfg, params, extra_inputs=extra, mesh=mesh)
+
+
+def make_requests(args: argparse.Namespace, cfg: ModelConfig,
+                  rng: np.random.Generator) -> list:
+    """``--requests`` random prompts of ``--prompt-len`` tokens, with SLA
+    tiers assigned by ``--sla-mix``."""
+    slas = parse_sla_mix(args.sla_mix, args.requests)
+    return [Request(uid=i,
+                    prompt=rng.integers(0, cfg.vocab, size=args.prompt_len),
+                    max_new=args.max_new, sla=slas[i])
+            for i in range(args.requests)]
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    use_compile_cache()
+    cfg = build_config(args)
     mesh = parse_mesh(args.mesh)
     serve_mesh = None
     if args.mesh_shape:
@@ -227,59 +301,11 @@ def main() -> None:
     mod = model_module(cfg)
 
     def run():
-        params = mod.init_lm(jax.random.PRNGKey(0), cfg)
-        extra = {}
         rng = np.random.default_rng(0)
-        if cfg.family == "vlm":
-            extra["images"] = jax.numpy.asarray(rng.standard_normal(
-                (args.batch, cfg.n_image_tokens, cfg.d_model),
-                dtype=np.float32))
-        if cfg.family == "encdec":
-            extra["frames"] = jax.numpy.asarray(rng.standard_normal(
-                (args.batch, cfg.n_frames, cfg.d_model), dtype=np.float32))
-        ccfg = ControllerConfig(enabled=args.controller,
-                                target_density=args.target_density,
-                                gain=args.ctrl_gain,
-                                audit_period=args.audit_period,
-                                adapt_capacity=args.adapt_capacity,
-                                per_tier=args.per_tier)
-        paged = (PagedKVConfig(block_size=args.paged_kv,
-                               pool_blocks=args.pool_blocks)
-                 if args.paged_kv else None)
-        mcfg = MetricsConfig(
-            enabled=bool(args.metrics or args.metrics_jsonl
-                         or args.metrics_trace or args.metrics_snapshot),
-            jsonl_path=args.metrics_jsonl,
-            trace=bool(args.metrics_trace),
-            trace_path=args.metrics_trace,
-            snapshot_path=args.metrics_snapshot)
-        srv = Server(mod, cfg, ServeConfig(batch=args.batch,
-                                           max_len=args.max_len,
-                                           max_new_tokens=args.max_new,
-                                           slot_refill=args.slot_refill,
-                                           controller=ccfg,
-                                           warm_buckets=args.warm_buckets,
-                                           prefill_chunk=args.prefill_chunk,
-                                           prefill_interleave=args
-                                           .prefill_interleave,
-                                           controller_ckpt=args
-                                           .controller_ckpt,
-                                           paged_kv=paged,
-                                           max_queue_depth=args
-                                           .max_queue_depth,
-                                           default_deadline_s=args
-                                           .default_deadline,
-                                           preempt=args.preempt,
-                                           pressure_gate=args
-                                           .pressure_gate,
-                                           metrics=mcfg),
-                     params, extra_inputs=extra, mesh=serve_mesh)
-        slas = parse_sla_mix(args.sla_mix, args.requests)
-        reqs = [Request(uid=i,
-                        prompt=rng.integers(0, cfg.vocab,
-                                            size=args.prompt_len),
-                        max_new=args.max_new, sla=slas[i])
-                for i in range(args.requests)]
+        srv = build_server(args, cfg, mod, init_params(mod, cfg), rng,
+                           mesh=serve_mesh)
+        reqs = make_requests(args, cfg, rng)
+        slas = [r.sla for r in reqs]
         t0 = time.perf_counter()
         done = srv.serve(reqs)
         dt = time.perf_counter() - t0
@@ -314,7 +340,7 @@ def main() -> None:
             rep["paged"] = srv.paged_stats()
         if srv.controller is not None:
             rep["controller"] = srv.controller.report()
-        if mcfg.enabled:
+        if srv.scfg.metrics.enabled:
             rep["metrics"] = srv.metrics_report()
             rep["metrics"]["sinks"] = {
                 k: v for k, v in (("jsonl", args.metrics_jsonl),

@@ -21,6 +21,7 @@ import jax
 from repro.configs.base import ModelConfig
 from repro.configs.registry import arch_names, get_config, reduced_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.specs import model_module
 from repro.optim.adamw import AdamWConfig
@@ -69,6 +70,7 @@ def main() -> None:
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     mesh = parse_mesh(args.mesh)

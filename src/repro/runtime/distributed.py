@@ -35,9 +35,12 @@ controller receives the exact ``(L, B)`` matrices it already consumes; the
 output combine is one all_gather over ``model`` carrying the partials plus
 the per-shard realized/union count columns, followed by a FIXED-ORDER sum
 over the full ms-length semantic shard axis — never a psum of f32 partials
-— so tokens and telemetry are BITWISE identical across every placement of
-the same (ds, ms) semantics, the invariant tests/test_distributed.py and
-the tests/test_mesh_properties.py property suite pin.
+— so this combine is BITWISE identical across every placement of the same
+(ds, ms) semantics.  Whole decode steps are too where the rest of the step
+rounds the same (XLA:CPU, or float32 on a TPU; the invariant
+tests/test_distributed.py and the tests/test_mesh_properties.py property
+suite pin); in bf16 on a TPU the partitioned attention rounds differently
+and tokens depend on placement (DESIGN.md §8).
 """
 from __future__ import annotations
 
@@ -60,16 +63,6 @@ COUNT_COLS = ("predicted", "realized", "actual", "false_neg", "union")
 # trailing rider columns packed next to the output partials so ONE
 # all_gather moves the partials and both per-shard skew signals
 _RIDER_COLS = 2   # realized, union
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (same shim as sharding/pipeline.py)."""
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 
 def semantic_grid(cfg: SM.SparseInferConfig) -> tuple[int, int]:
@@ -480,7 +473,8 @@ def shard_map_apply(params: dict, x: jax.Array, cfg: SM.SparseInferConfig,
             return cat(y_rows)
         return cat(y_rows), cat(tot_rows), cat(real_rows), cat(union_rows)
 
-    fn = _shard_map(body, mesh, in_specs, out_specs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     with R.shard_local():   # the body works on per-shard values: no nested
         out = fn(sign_wg, *w_ops, x, a)
     if not return_stats:

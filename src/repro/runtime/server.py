@@ -664,8 +664,9 @@ class Server:
         over the 'data' axis (DESIGN.md §8): tokens (B, 1), cache lengths
         (B,), and the (L, B) alpha matrix each land pre-sharded so the
         jitted decode step never re-lays them out.  Placement only — the
-        values (and therefore the decoded tokens) are identical without a
-        mesh."""
+        values are identical without a mesh; the decoded tokens are too in
+        float32, but not in bf16, where the partitioned reductions round
+        differently (DESIGN.md §8)."""
         jt, jl = jnp.asarray(tok), jnp.asarray(lengths)
         ja = None if alphas is None else jnp.asarray(alphas)
         jtab = None if table is None else jnp.asarray(table)
@@ -766,10 +767,11 @@ class Server:
         return ladder[-1]
 
     def _check_shard_grids(self, caps: tuple) -> None:
-        """Warn — once per (bucket, shard), deduplicated across decode
-        steps and bucket switches — when a shard's pallas kernel grid is
-        degenerate at its local dims for its ACTIVE bucket, so the jnp
-        oracle fallback is visible without spamming the serve loop."""
+        """Check — once per (bucket, shard), deduplicated across decode
+        steps and bucket switches — that a shard's pallas kernel grid tiles
+        at its local dims for its ACTIVE bucket.  On a TPU an untileable
+        grid raises (the compiled kernels never substitute the jnp
+        oracle); in interpret mode it warns that the oracle runs."""
         if self.cfg.sparse.strategy != "pallas":
             return
         from repro.core.predictor import packed_width
@@ -788,10 +790,12 @@ class Server:
                                    n_shards=len(caps),
                                    capacity_groups=capg)
             except ValueError as e:
-                warnings.warn(
-                    f"sharded pallas predictor grid is degenerate for "
-                    f"shard {s} at bucket {capg} local groups ({e}); the "
-                    "shard runs the jnp oracle fallback", stacklevel=2)
+                msg = (f"sharded pallas predictor grid is degenerate for "
+                       f"shard {s} at bucket {capg} local groups ({e})")
+                if jax.default_backend() == "tpu":
+                    raise ValueError(msg + ": no TPU kernel tiling") from e
+                warnings.warn(msg + "; interpret mode runs the jnp oracle",
+                              stacklevel=2)
 
     def _select_bucket(self):
         """Pick the smallest pre-jitted capacity bucket covering the

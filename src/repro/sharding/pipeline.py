@@ -93,13 +93,8 @@ def pipeline_apply(block_fn: Callable, stage_weights, x, *,
     in_specs = (jax.tree.map(lambda _: P(axis), stage_weights,
                              is_leaf=lambda a: hasattr(a, "shape")),
                 P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        fn = jax.shard_map(stage_loop, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(), check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(stage_loop, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(), check_rep=False)
+    fn = jax.shard_map(stage_loop, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     return fn(stage_weights, x)
 
 

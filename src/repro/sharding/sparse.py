@@ -121,54 +121,11 @@ def mlp_param_spec(name: str, shape: tuple) -> P:
     return P(*((None,) * pad), "model", None)
 
 
-def _qk_replication_workaround_needed() -> bool:
-    """Whether this jax's SPMD partitioner still needs the 2D-mesh q/k
-    replication guard in ``serve_param_shardings``.
-
-    The miscompile was observed on jax 0.4.x (0.4.37 in the pinned
-    container): column-sharding the q/k projections sub-head over 'model'
-    while a non-trivial 'data' axis is present produces ~1.5 absolute
-    logit error in prefill.  The partitioner was reworked for the 0.5
-    line, so the guard auto-lifts there — and the regression test
-    (tests/test_distributed.py::test_2d_placed_prefill_matches_unplaced)
-    compares placed vs unplaced outputs either way: if a future jax
-    regresses, the test catches it rather than this version fence."""
-    try:
-        ver = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    except ValueError:        # dev/dirty version strings: keep the guard
-        return True
-    return ver < (0, 5)
-
-
 def serve_param_shardings(params, mesh=None):
     """NamedShardings for the whole serve-path param tree (TP over 'model',
-    replicated over data axes — ``rules`` mode='serve').
-
-    2D-mesh caveat (DESIGN.md §8): when the mesh has BOTH a non-trivial
-    'data' axis and a non-trivial 'model' axis, only the sparse-MLP leaves
-    (``SPARSE_MLP_KEYS``) keep their row sharding — they execute under the
-    fixed-order shard_map combine, which is placement-deterministic by
-    construction.  The attention/embedding leaves are replicated: jax
-    0.4.x's SPMD partitioner MISCOMPUTES prefill when the q/k projections
-    are column-sharded sub-head over 'model' while a 'data' axis is also
-    present (observed ~1.5 absolute logit error, not float noise;
-    tests/test_distributed.py::test_2d_placed_prefill_matches_unplaced
-    pins the workaround).  Single-axis meshes (1×m, d×1) are unaffected
-    and keep the full placement; fixed jax versions (>= 0.5) lift the
-    guard automatically (``_qk_replication_workaround_needed``)."""
+    replicated over data axes — ``rules`` mode='serve')."""
     mesh = mesh or R.current_mesh()
     specs = R.param_specs(params, mode="serve", mesh=mesh)
-    if (mesh_shard_count(mesh) > 1 and mesh_data_count(mesh) > 1
-            and _qk_replication_workaround_needed()):
-        from jax.sharding import PartitionSpec as PS
-
-        def guard(path, spec):
-            name = _path_leaf(path)
-            return spec if name in SPARSE_MLP_KEYS else PS()
-
-        specs = jax.tree_util.tree_map_with_path(
-            guard, specs,
-            is_leaf=lambda s: isinstance(s, P))
     return R.named_shardings(specs, mesh)
 
 

@@ -3,7 +3,9 @@ forced multi-device host platform for the sharded-serving tests.
 
 The tier-1 suite is compile-bound (dozens of small jitted models), so a
 persistent cache cuts repeat runs roughly in half.  Cache misses (first run,
-jax upgrade) only cost the compiles the run would have done anyway.
+jax upgrade) only cost the compiles the run would have done anyway.  The
+cache location follows ``repro.launch.compile_cache`` (the environment's
+``JAX_COMPILATION_CACHE_DIR``, else the checkout's ``.jax_cache/``).
 
 The 8-device host platform (set BEFORE jax initializes) backs the
 tests/test_distributed.py and tests/test_mesh_properties.py mesh fixtures:
@@ -22,11 +24,8 @@ if "xla_force_host_platform_device_count" not in _flags:
 
 import jax  # noqa: E402  (the flag must precede jax's backend init)
 
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
+
 jax.config.update("jax_platform_name", "cpu")
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(os.path.dirname(__file__), "..",
-                                   ".pytest_cache", "jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
-except Exception:  # older jax without the persistent cache — fine
-    pass
+use_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)

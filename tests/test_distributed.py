@@ -599,6 +599,25 @@ class TestPerShardBuckets:
         # repeated (128, s1) is deduped
         assert len(msgs) == 3, msgs
 
+    def test_degenerate_grid_raises_on_tpu(self, monkeypatch):
+        """On a TPU backend the compiled kernels never run the jnp oracle,
+        so an untileable shard grid is an error, not a warning."""
+        from repro.kernels import ops as kops
+        from repro.runtime import server as server_mod
+
+        def boom(*a, **kw):
+            raise ValueError("forced degenerate tile")
+
+        srv = self._srv()
+        monkeypatch.setattr(kops, "choose_blocks", boom)
+        monkeypatch.setattr(server_mod.jax, "default_backend",
+                            lambda: "tpu")
+        srv.cfg = srv.cfg.replace(sparse=dataclasses.replace(
+            srv.cfg.sparse, strategy="pallas"))
+        srv._grid_warned.clear()
+        with pytest.raises(ValueError, match="no TPU kernel tiling"):
+            srv._check_shard_grids((64, 128))
+
 
 class TestControllerPersistence:
     """Satellite: controller state survives server restarts (ROADMAP item).

@@ -18,28 +18,40 @@ def rand(key, shape, dtype):
     return x.astype(dtype)
 
 
+def _np_pack(v) -> np.ndarray:
+    """Loop-free numpy reference for core.predictor.pack_signs: LSB-first
+    sign bits of the last axis, zero-padded to whole 32-bit words."""
+    neg = np.asarray(v) < 0
+    pad = -neg.shape[-1] % 32
+    neg = np.pad(neg, [(0, 0)] * (neg.ndim - 1) + [(0, pad)])
+    bits = neg.reshape(neg.shape[:-1] + (-1, 32)).astype(np.uint64)
+    words = (bits << np.arange(32, dtype=np.uint64)).sum(-1)
+    return words.astype(np.uint32).view(np.int32)
+
+
 class TestSignPack:
+    """The XLA sign packer feeds the predictor kernel its packed input."""
+
     @pytest.mark.parametrize("rows,d", [(8, 32), (16, 128), (64, 2048),
                                         (13824 // 32, 5120 // 4), (5, 96)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
     def test_matches_ref(self, rows, d, dtype):
         v = rand(KEY, (rows, d), dtype)
-        out = ops.sign_pack(v, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(ref.sign_pack_ref(v)))
+        np.testing.assert_array_equal(np.asarray(P.pack_signs(v)),
+                                      _np_pack(v))
 
     def test_odd_width_falls_back(self):
+        """d not a multiple of 32: padded lanes pack as positive bits."""
         v = jax.random.normal(KEY, (4, 37))
-        out = ops.sign_pack(v, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(ref.sign_pack_ref(v)))
+        out = P.pack_signs(v)
+        assert out.shape == (4, 2)
+        np.testing.assert_array_equal(np.asarray(out), _np_pack(v))
 
     def test_leading_batch_dims(self):
         v = jax.random.normal(KEY, (2, 3, 64))
-        out = ops.sign_pack(v, interpret=True)
+        out = P.pack_signs(v)
         assert out.shape == (2, 3, 2)
-        np.testing.assert_array_equal(np.asarray(out),
-                                      np.asarray(ref.sign_pack_ref(v)))
+        np.testing.assert_array_equal(np.asarray(out), _np_pack(v))
 
 
 class TestPredictCounts:
@@ -71,7 +83,7 @@ class TestTilingGuards:
     def test_choose_block_k_typical_shapes(self):
         from repro.kernels.predict import choose_block_k
         assert choose_block_k(1024, 8, 4) == 1024          # fits the budget
-        assert choose_block_k(13824, 160, 1) == 4608       # divisor under it
+        assert choose_block_k(13824, 160, 1) == 3200       # lane-aligned cut
         assert choose_block_k(512, 16, 2, group_size=8) == 512
         bk = choose_block_k(4096, 128, 16)                 # budget-bound
         assert 8 <= bk <= 4096 and 4096 % bk == 0
@@ -88,56 +100,78 @@ class TestTilingGuards:
             choose_block_k(k, w, b)
 
     def test_choose_block_k_rejects_huge_batch(self):
-        """A (B, bk, w) tile that can't fit even 8 rows must error, not
-        silently degrade to one-row tiles."""
+        """The batch no longer shrinks the tile (tokens run one at a time
+        within it), but a row too wide for one 128-lane tile must error,
+        not silently degrade to narrower tiles."""
         from repro.kernels.predict import choose_block_k
+        assert choose_block_k(4096, 4096, 64) == choose_block_k(4096, 4096, 1)
         with pytest.raises(ValueError, match="degenerate"):
-            choose_block_k(4096, 4096, 64)
+            choose_block_k(4096, 8192, 64)
 
     def test_choose_block_k_rejects_indivisible_group(self):
         from repro.kernels.predict import choose_block_k
         with pytest.raises(ValueError, match="divisible"):
             choose_block_k(100, 4, 1, group_size=8)
 
-    def test_choose_blocks_typical_shapes(self):
-        from repro.kernels.sign_pack import choose_blocks
-        assert choose_blocks(64, 2048) == (64, 2048)
-        bm, bd = choose_blocks(13824 // 32, 5120 // 4)
-        assert (13824 // 32) % bm == 0 and (5120 // 4) % bd == 0
-
-    def test_choose_blocks_rejects_unpackable_d(self):
-        from repro.kernels.sign_pack import choose_blocks
-        with pytest.raises(ValueError, match="32"):
-            choose_blocks(8, 100)
-
-    def test_choose_blocks_rejects_prime_rows_over_budget(self):
-        """rows with no divisor >= 8 under the VMEM row budget (2·1021 at
-        d=1024 -> budget 512) must error, not tile 2 rows at a time."""
-        from repro.kernels.sign_pack import choose_blocks
-        with pytest.raises(ValueError, match="degenerate"):
-            choose_blocks(2 * 1021, 1024)
-
-    def test_ops_fall_back_on_degenerate_shapes(self):
-        """The dispatch layer absorbs the guard errors: results still match
-        the oracle for shapes the kernels refuse to tile."""
-        from repro.kernels import ref
-        v = jax.random.normal(KEY, (2 * 1021, 1024))  # rows guard -> oracle
-        np.testing.assert_array_equal(
-            np.asarray(ops.sign_pack(v, interpret=True)),
-            np.asarray(ref.sign_pack_ref(v)))
-        # k = 2·1021 (1021 prime) over-budget at w=128, b=16: no divisor
-        # tile >= 8 exists under the VMEM budget -> guard fires -> oracle
-        k, d, b = 2 * 1021, 4096, 16
+    def test_choose_block_k_lane_aligned_overhang(self):
+        """ProSparse-Llama2-7B: k/G = 1376 has no 128-lane divisor, so the
+        tile is lane-aligned and the last one overhangs k."""
         from repro.kernels.predict import choose_block_k
-        with pytest.raises(ValueError, match="degenerate|no non-degenerate"):
-            choose_block_k(k, d // 32, b)
+        bk = choose_block_k(11008, 128, 4, group_size=8)
+        assert (bk // 8) % 128 == 0 and 11008 % bk != 0
+
+    @pytest.mark.parametrize("k,b", [(1376, 4), (1376, 20)])
+    def test_overhanging_tiles_match_ref(self, k, b):
+        """A k tile past the end of k (cdiv grid) and a partial token tile
+        (b not a multiple of 8) leave every real row bitwise unchanged."""
+        from repro.kernels import predict as KP
+        d, g = 256, 8
+        w = jax.random.normal(KEY, (k, d))
+        x = jax.random.normal(jax.random.PRNGKey(7), (b, d))
+        pw, a = P.pack_signs(w), jnp.full((b,), 1.02)
+        gm, cnt = KP.predict_group_margins(pw, P.pack_signs(x), a, d_valid=d,
+                                           group_size=g, interpret=True,
+                                           block_k=1024)
+        gm_ref, cnt_ref = jax.jit(
+            ref.predict_group_margins_ref, static_argnums=(2, 4))(
+                pw, x, d, a, g)
+        np.testing.assert_array_equal(np.asarray(gm), np.asarray(gm_ref))
+        np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_ref))
+
+    def test_compiled_refuses_untileable_shape(self, monkeypatch):
+        """Compiled (not interpreting), a shape no kernel tile fits raises
+        with the shape instead of running the jnp oracle."""
+        from repro.kernels import predict as KP
+        # a 2 MiB budget leaves 512 rows at w = 256 words: below one
+        # lane-aligned 1024-row tile of 8-row groups
+        monkeypatch.setattr(KP, "VMEM_BUDGET_BYTES", 2 * 1024 * 1024)
+        k, d, b = 2048, 8192, 8
+        pw = jnp.zeros((k, d // 32), jnp.int32)
+        x = jnp.zeros((b, d), jnp.float32)
+        with pytest.raises(ValueError, match="no TPU tiling.*k=2048"):
+            ops.predict_group_margins(pw, x, d, 1.0, group_size=8,
+                                      interpret=False)
+
+    def test_ops_fall_back_on_degenerate_shapes(self, monkeypatch):
+        """In interpret mode the dispatch layer absorbs the guard errors:
+        results still match the oracle for shapes the kernels refuse to
+        tile."""
+        from repro.kernels import ref
+        from repro.kernels import predict as KP
+        # a 2 MiB budget at w = 256 words leaves 512 rows, below one
+        # lane-aligned 1024-row tile of 8-row groups -> guard -> oracle
+        monkeypatch.setattr(KP, "VMEM_BUDGET_BYTES", 2 * 1024 * 1024)
+        k, d, b = 2048, 8192, 8
+        from repro.kernels.predict import choose_block_k
+        with pytest.raises(ValueError, match="degenerate"):
+            choose_block_k(k, d // 32, b, group_size=8)
         w = jax.random.normal(KEY, (k, d)) * 0.1
         x = jax.random.normal(jax.random.PRNGKey(1), (b, d))
         pw = P.pack_signs(w)
-        gm, cnt = ops.predict_group_margins(pw, x, d, 1.0, group_size=1,
+        gm, cnt = ops.predict_group_margins(pw, x, d, 1.0, group_size=8,
                                             interpret=True)
         gm_ref, cnt_ref = ref.predict_group_margins_ref(
-            pw, x, d, jnp.full((b,), 1.0), 1)
+            pw, x, d, jnp.full((b,), 1.0), 8)
         np.testing.assert_allclose(np.asarray(gm), np.asarray(gm_ref),
                                    rtol=1e-6, atol=1e-6)
         np.testing.assert_array_equal(np.asarray(cnt), np.asarray(cnt_ref))

@@ -50,7 +50,6 @@ from repro.models import lm
 from repro.runtime.kv_pool import KVPool, PoolExhausted
 from repro.runtime.server import (Request, Server, ServeConfig,
                                   throughput_report)
-from repro.sharding import sparse as SHS
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -821,20 +820,6 @@ class TestRequestStampReset:
             assert r.ttft_s > 0.0 and r.latency_s >= r.ttft_s
         rep = throughput_report(reqs)
         assert np.isfinite(rep["tok_per_s"]) and rep["tok_per_s"] > 0.0
-
-
-class TestQKWorkaroundVersionGate:
-    """The 2D-mesh q/k replication workaround in sharding/sparse.py is
-    fenced to jax < 0.5: fixed versions lift it automatically, and a
-    garbled version string keeps it (fail safe)."""
-
-    @pytest.mark.parametrize("ver,needed", [
-        ("0.4.37", True), ("0.4.9", True), ("0.5.0", False),
-        ("0.6.2", False), ("1.0", False), ("0.5.0.dev20250101", False),
-        ("garbage.version", True)])
-    def test_gate(self, monkeypatch, ver, needed):
-        monkeypatch.setattr(SHS.jax, "__version__", ver)
-        assert SHS._qk_replication_workaround_needed() is needed
 
 
 class TestBenchDiffGate:

@@ -348,11 +348,12 @@ class TestHbmBytesWeightDtype:
 
     def test_scale_bytes_itemized(self):
         """Scale traffic follows the §13 layout: (rows, d/qg) f32 tiles for
-        gate+up plus ONE (1, d) f32 row per selected group for down-proj."""
+        gate+up per selected group plus the resident (k/qg, d) f32
+        down-proj scale matrix, read once per call."""
         _, q = self._pair(128)
         sel_rows = 128 * self.G
         want = (2 * sel_rows * (self.D // self.QG) * 4    # wg + wu scales
-                + 128 * self.D * 4)                       # wd scale rows
+                + (self.K // self.QG) * self.D * 4)       # wd scale matrix
         assert q["fused_scale_bytes"] == want
 
     def test_act_bytes_decoupled(self):
