@@ -52,6 +52,7 @@ import numpy as np
 from repro.configs.base import MetricsConfig, ModelConfig, PagedKVConfig
 from repro.models import lm
 from repro.runtime.faults import FaultInjector
+from repro.runtime.metrics import capture
 from repro.runtime.metrics import nearest_rank_pct as _pct
 from repro.runtime.server import Request, Server, ServeConfig, \
     throughput_report
@@ -292,9 +293,10 @@ def main() -> None:
                     help="write the chunked serve's structured metrics "
                          "event stream (JSON lines; schema-gated by "
                          "runtime.metrics.validate_jsonl in CI)")
-    ap.add_argument("--metrics-trace", default="", metavar="PATH",
-                    help="write the chunked serve's Perfetto trace_event "
-                         "JSON (nightly artifact)")
+    ap.add_argument("--metrics-trace", default="", metavar="DIR",
+                    help="capture the chunked serve with jax.profiler into "
+                         "DIR: serve.* spans and device ops on one clock, "
+                         "with a Perfetto trace (nightly artifact)")
     ap.add_argument("--append-history", default="", metavar="PATH",
                     help="append a one-line run summary (key metrics + "
                          "git sha) to this JSONL trajectory file")
@@ -306,9 +308,7 @@ def main() -> None:
                       d_ff=4 * d, max_seq=256, dtype="float32",
                       param_dtype="float32", attn_chunk=256, remat=False)
     smoke_mcfg = MetricsConfig(enabled=True,
-                               jsonl_path=args.metrics_jsonl,
-                               trace=bool(args.metrics_trace),
-                               trace_path=args.metrics_trace)
+                               jsonl_path=args.metrics_jsonl)
     if args.study_only:
         study = paged_kv_study(cfg, args.quick, mcfg=smoke_mcfg)
         print(f"paged_kv_study,reduction={study['turn2_chunk_reduction']:.3f},"
@@ -337,17 +337,21 @@ def main() -> None:
     max_new = 8 if args.quick else 16
     mk = lambda pc: ServeConfig(batch=4, max_len=256, prefill_chunk=pc,
                                 prefill_interleave=args.interleave)
+    # both serves run with the hub enabled (the report rides its
+    # histogram/watchdog snapshot); file sinks and the profiler capture
+    # only on the chunked side
+    monolithic = _serve(cfg, mk(0), n_req, max_new,
+                        mcfg=MetricsConfig(enabled=True))
+    with capture(args.metrics_trace):
+        chunked = _serve(cfg, mk(args.chunk), n_req, max_new,
+                         mcfg=smoke_mcfg)
     report = {
         "shape": {"d_model": d, "n_layers": 4, "batch": 4, "max_len": 256,
                   "requests": n_req, "max_new": max_new,
                   "chunk": args.chunk, "interleave": args.interleave},
         "backend": jax.default_backend(),
-        # both serves run with the hub enabled (the report rides its
-        # histogram/watchdog snapshot); file sinks only on the chunked side
-        "monolithic": _serve(cfg, mk(0), n_req, max_new,
-                             mcfg=MetricsConfig(enabled=True)),
-        "chunked": _serve(cfg, mk(args.chunk), n_req, max_new,
-                          mcfg=smoke_mcfg),
+        "monolithic": monolithic,
+        "chunked": chunked,
         "paged_kv_study": paged_kv_study(cfg, args.quick,
                                          mcfg=MetricsConfig(enabled=True)),
         "overload_study": overload_study(cfg, args.quick),

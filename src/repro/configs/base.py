@@ -135,7 +135,7 @@ class MetricsConfig:
     """First-class observability for the serve stack (DESIGN.md §12).
 
     Configures ``runtime/metrics.py:MetricsHub`` — the counter/gauge/
-    histogram registry, serve-phase tracing, and structured sinks the
+    histogram registry, span histograms, and structured sinks the
     server emits into.  Disabled (the default) the hub is a strict no-op:
     every emit method returns immediately and the serve path is
     bitwise-identical to a metrics-free build (pinned by
@@ -145,9 +145,6 @@ class MetricsConfig:
     enabled: bool = False
     jsonl_path: str = ""        # JSONL event-stream sink ("" = in-memory
                                 # ring only; see MetricsHub.events())
-    trace: bool = False         # record Chrome/Perfetto trace events even
-                                # with no trace_path (read via trace_events())
-    trace_path: str = ""        # write trace_event JSON here on flush()
     snapshot_path: str = ""     # write Prometheus-style exposition on flush()
     cadence: int = 8            # publish gauge families (controller/pool/
                                 # shard state) every N decode steps — emission
@@ -161,7 +158,7 @@ class MetricsConfig:
                                 # () = metrics.DEFAULT_BUCKETS
     watchdog: bool = True       # hook jax compile events: any post-warmup
                                 # retrace warns + counts (DESIGN.md §12)
-    events_keep: int = 4096     # in-memory ring sizes (events + trace)
+    events_keep: int = 4096     # in-memory event ring size
 
 
 @dataclasses.dataclass(frozen=True)

@@ -318,7 +318,8 @@ def masked_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
     """
     params = _dense_params(params)
     act = _act(cfg)
-    m = _margins(params, x, alpha)          # (..., k)
+    with jax.named_scope("predict"):
+        m = _margins(params, x, alpha)      # (..., k)
     keep = (m <= 0).astype(x.dtype)
     g1 = act(x @ params["wg_t"].T.astype(x.dtype))
     h1 = g1 * keep
@@ -375,19 +376,22 @@ def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
         if (k // g) % msz == 0 and cap % msz == 0:
             ms = msz
 
-    m_tok = _margins(params, xg, alpha)           # (G, B, k) per-token
-    m = jax.vmap(S.union_margin)(m_tok)           # (G, k) batch union
-    gm = jax.vmap(lambda mm: S.group_margins(mm, g))(m)   # (G, k/g)
-    gm = gm.reshape(ngrp, ms, (k // g) // ms)     # (G, ms, k/g/ms)
-    gm = R.shard(gm, None, "model", None)
-    sel, sstats = jax.vmap(jax.vmap(
-        lambda mm: S.capacity_select_with_stats(mm, cap // ms)))(gm)
+    with jax.named_scope("predict"):
+        m_tok = _margins(params, xg, alpha)       # (G, B, k) per-token
+    with jax.named_scope("select"):
+        m = jax.vmap(S.union_margin)(m_tok)       # (G, k) batch union
+        gm = jax.vmap(lambda mm: S.group_margins(mm, g))(m)   # (G, k/g)
+        gm = gm.reshape(ngrp, ms, (k // g) // ms)  # (G, ms, k/g/ms)
+        gm = R.shard(gm, None, "model", None)
+        sel, sstats = jax.vmap(jax.vmap(
+            lambda mm: S.capacity_select_with_stats(mm, cap // ms)))(gm)
+        if ms > 1:
+            sel = S.Selection(R.shard(sel.indices, None, "model", None),
+                              R.shard(sel.valid, None, "model", None),
+                              sel.count)
     cl = cap // ms                                # local capacity per shard
-    if ms > 1:
-        sel = S.Selection(R.shard(sel.indices, None, "model", None),
-                          R.shard(sel.valid, None, "model", None),
-                          sel.count)
 
+    @jax.named_scope("gather")
     def take_rows(w_t):
         w_grouped = w_t.reshape(ms, (k // g) // ms, g, d)
         w_grouped = R.shard(w_grouped, "model", None, None, None)
@@ -495,10 +499,12 @@ def pallas_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
     # over — identical contract, bitwise-equal per-row results (the
     # predictor tiles tokens itself)
     chunked = b > cfg.sparse_max_batch
-    gm_tok, pred_cnt = kops.predict_group_margins(
-        sign_wg, xb, d, a, group_size=g, interpret=interpret)
-    gm = S.union_margin(gm_tok)                   # (k/g,) batch/chunk union
-    sel, sstats = S.capacity_select_with_stats(gm, cap)
+    with jax.named_scope("predict"):
+        gm_tok, pred_cnt = kops.predict_group_margins(
+            sign_wg, xb, d, a, group_size=g, interpret=interpret)
+    with jax.named_scope("select"):
+        gm = S.union_margin(gm_tok)               # (k/g,) batch/chunk union
+        sel, sstats = S.capacity_select_with_stats(gm, cap)
 
     if quantized:
         fused = (kops.fused_sparse_mlp_chunk_q if chunked

@@ -126,6 +126,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         interpret=interpret,
+        name="paged_attention",
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), q,
       k_pages, v_pages)
 
@@ -166,5 +167,6 @@ def paged_kv_write(pages: jax.Array, vals: jax.Array, blocks: jax.Array,
         out_shape=jax.ShapeDtypeStruct(pages.shape, pages.dtype),
         input_output_aliases={3: 0},
         interpret=interpret,
+        name="paged_kv_write",
     )(blocks.astype(jnp.int32), offsets.astype(jnp.int32),
       vals.astype(pages.dtype), pages)

@@ -112,6 +112,7 @@ def predict_counts(packed_w: jax.Array, packed_x: jax.Array, *,
         out_specs=pl.BlockSpec((bt, bk), lambda i, t: (t, i)),
         out_shape=jax.ShapeDtypeStruct((b, k), jnp.int32),
         interpret=interpret,
+        name="predict_counts",
     )(packed_w, packed_x)
 
 
@@ -180,5 +181,6 @@ def predict_group_margins(packed_w: jax.Array,
         out_specs=pl.BlockSpec((bt, bk // group_size), lambda i, t: (t, i)),
         out_shape=jax.ShapeDtypeStruct((b, k // group_size), jnp.float32),
         interpret=interpret,
+        name="predict_group_margins",
     )(packed_x, packed_w, a)
     return gm, jnp.sum(gm <= 0, axis=-1, dtype=jnp.int32)

@@ -262,6 +262,7 @@ def _fused_call(x, weights, wd_s, sel_indices, sel_count, gm_tok, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_sparse_mlp",
     )(sel_indices.astype(jnp.int32), cnt, *operands)
 
 

@@ -28,6 +28,7 @@ from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.specs import model_module
 from repro.launch.train import parse_mesh
+from repro.runtime.metrics import capture
 from repro.runtime.server import Request, Server, ServeConfig, \
     throughput_report
 
@@ -185,9 +186,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="append structured serve events (admissions, "
                          "first tokens, completions, sheds, preemptions, "
                          "bucket switches, retraces) as JSON lines")
-    ap.add_argument("--metrics-trace", default="", metavar="PATH",
-                    help="write a Chrome/Perfetto trace_event JSON of the "
-                         "serve phases (load in ui.perfetto.dev)")
+    ap.add_argument("--metrics-trace", default="", metavar="DIR",
+                    help="run the serve under a jax.profiler capture into "
+                         "DIR: the serve.* phase spans, the model step's "
+                         "device ops and the transfers on one clock "
+                         "(perfetto_trace.json.gz opens in ui.perfetto.dev)")
     ap.add_argument("--metrics-snapshot", default="", metavar="PATH",
                     help="write a Prometheus-style text exposition of all "
                          "instruments at each serve drain")
@@ -257,8 +260,6 @@ def build_server(args: argparse.Namespace, cfg: ModelConfig, mod,
         enabled=bool(args.metrics or args.metrics_jsonl
                      or args.metrics_trace or args.metrics_snapshot),
         jsonl_path=args.metrics_jsonl,
-        trace=bool(args.metrics_trace),
-        trace_path=args.metrics_trace,
         snapshot_path=args.metrics_snapshot)
     scfg = ServeConfig(batch=args.batch, max_len=args.max_len,
                        max_new_tokens=args.max_new,
@@ -306,9 +307,10 @@ def main() -> None:
                            mesh=serve_mesh)
         reqs = make_requests(args, cfg, rng)
         slas = [r.sla for r in reqs]
-        t0 = time.perf_counter()
-        done = srv.serve(reqs)
-        dt = time.perf_counter() - t0
+        with capture(args.metrics_trace):
+            t0 = time.perf_counter()
+            done = srv.serve(reqs)
+            dt = time.perf_counter() - t0
         rep = throughput_report(done)
         rep["wall_s"] = dt
         rep["scheduler"] = ("slot_refill" if args.slot_refill else "chunked")

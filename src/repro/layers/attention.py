@@ -231,6 +231,7 @@ def _quantize_kv(x: jax.Array):
     return q, scale.astype(jnp.bfloat16)
 
 
+@jax.named_scope("kv_write")
 def update_kv_cache(cache: dict, k_new: jax.Array, v_new: jax.Array,
                     index: jax.Array) -> dict:
     """Insert (B, S_new, K, hd) at sequence offset `index`.
@@ -388,6 +389,7 @@ def q_pos_sentinel(s_max: int, cache_len: jax.Array) -> jax.Array:
 # +0.0, so it contributes nothing (the same kv_pad-to-width denominator
 # argument chunked prefill uses, DESIGN.md §9).
 
+@jax.named_scope("kv_write")
 def paged_update_kv(pool: dict, k_new: jax.Array, v_new: jax.Array,
                     table: jax.Array, cache_len: jax.Array) -> dict:
     """Scatter one token per slot into the pool: (B,1,K,hd) K/V at per-slot
@@ -462,8 +464,9 @@ def paged_decode_attend(params: dict, x: jax.Array, cfg: AttentionConfig,
         blk = jnp.take_along_axis(table, (cl // bs)[:, None], axis=1)[:, 0]
         off = cl % bs
         new_pool = dict(pool)
-        new_pool["k"] = ops.paged_kv_write(pool["k"], k[:, 0], blk, off)
-        new_pool["v"] = ops.paged_kv_write(pool["v"], v[:, 0], blk, off)
+        with jax.named_scope("kv_write"):
+            new_pool["k"] = ops.paged_kv_write(pool["k"], k[:, 0], blk, off)
+            new_pool["v"] = ops.paged_kv_write(pool["v"], v[:, 0], blk, off)
         ctx = ops.paged_attention(q[:, 0], new_pool["k"], new_pool["v"],
                                   table, cl, softcap=cfg.softcap,
                                   window=cfg.window)

@@ -209,37 +209,42 @@ def _block_decode(blk, x, cfg: ModelConfig, cache, cache_len, window, alpha,
     is then this layer's pool leaves, DESIGN.md §10).
     """
     from repro.core import sparse_mlp as SM
-    h = C.norm_apply(cfg, blk["ln1"], x)
-    acfg = C.attn_cfg(cfg, window=window)
-    attn_params = blk["attn"]
-    if lora is not None:
-        attn_params = dict(attn_params)
-        attn_params["wq"] = attn_params["wq"] + (
-            lora["lora_a"] @ lora["lora_b_q"]).astype(attn_params["wq"].dtype)
-    if block_table is not None:
-        h, cache = A.paged_decode_attend(attn_params, h, acfg, cache,
-                                         cache_len, block_table)
-    else:
-        h, cache = A.decode_attend(attn_params, h, acfg, cache, cache_len)
-    if cfg.post_block_norm:
-        h = C.norm_apply(cfg, blk["ln1_post"], h)
+    with jax.named_scope("attention"):
+        h = C.norm_apply(cfg, blk["ln1"], x)
+        acfg = C.attn_cfg(cfg, window=window)
+        attn_params = blk["attn"]
+        if lora is not None:
+            attn_params = dict(attn_params)
+            attn_params["wq"] = attn_params["wq"] + (
+                lora["lora_a"] @ lora["lora_b_q"]).astype(
+                    attn_params["wq"].dtype)
+        if block_table is not None:
+            h, cache = A.paged_decode_attend(attn_params, h, acfg, cache,
+                                             cache_len, block_table)
+        else:
+            h, cache = A.decode_attend(attn_params, h, acfg, cache,
+                                       cache_len)
+        if cfg.post_block_norm:
+            h = C.norm_apply(cfg, blk["ln1_post"], h)
     x = x + h
-    h = C.norm_apply(cfg, blk["ln2"], x)
     stats = None
-    if "moe" in blk:
-        h, _ = moe_apply(blk["moe"], h, moe_cfg(cfg))
-        if collect_stats:
-            # tp_shards keeps the pytree structure aligned with sharded
-            # sparse layers' stats (they carry the per-shard rider key)
-            stats = SM.zero_mlp_stats((x.shape[0],), cfg.sparse.tp_shards)
-    elif collect_stats:
-        h, stats = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg), decode=True,
-                             alpha=alpha, return_stats=True)
-    else:
-        h = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg), decode=True,
-                      alpha=alpha)
-    if cfg.post_block_norm:
-        h = C.norm_apply(cfg, blk["ln2_post"], h)
+    with jax.named_scope("mlp"):
+        h = C.norm_apply(cfg, blk["ln2"], x)
+        if "moe" in blk:
+            h, _ = moe_apply(blk["moe"], h, moe_cfg(cfg))
+            if collect_stats:
+                # tp_shards keeps the pytree structure aligned with sharded
+                # sparse layers' stats (they carry the per-shard rider key)
+                stats = SM.zero_mlp_stats((x.shape[0],),
+                                          cfg.sparse.tp_shards)
+        elif collect_stats:
+            h, stats = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
+                                 decode=True, alpha=alpha, return_stats=True)
+        else:
+            h = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg), decode=True,
+                          alpha=alpha)
+        if cfg.post_block_norm:
+            h = C.norm_apply(cfg, blk["ln2_post"], h)
     return x + h, cache, stats
 
 
@@ -271,34 +276,38 @@ def _block_chunk_fwd(blk, x, cfg: ModelConfig, cache, offset, valid, window,
     """
     from repro.core import sparse_mlp as SM
     b, s = x.shape[0], x.shape[1]
-    h = C.norm_apply(cfg, blk["ln1"], x)
-    acfg = C.attn_cfg(cfg, window=window)
-    h, cache = A.chunk_attend(blk["attn"], h, acfg, cache, offset, valid,
-                              q_chunk=cfg.attn_chunk, kv_chunk=cfg.attn_chunk)
-    if cfg.post_block_norm:
-        h = C.norm_apply(cfg, blk["ln1_post"], h)
+    with jax.named_scope("attention"):
+        h = C.norm_apply(cfg, blk["ln1"], x)
+        acfg = C.attn_cfg(cfg, window=window)
+        h, cache = A.chunk_attend(blk["attn"], h, acfg, cache, offset, valid,
+                                  q_chunk=cfg.attn_chunk,
+                                  kv_chunk=cfg.attn_chunk)
+        if cfg.post_block_norm:
+            h = C.norm_apply(cfg, blk["ln1_post"], h)
     x = R.shard_activations(x + h, sp=cfg.sp_activations)
-    h = C.norm_apply(cfg, blk["ln2"], x)
     wmean = lambda a: _chunk_stat_mean(a, tok_mask)
     stats = None
-    if "moe" in blk:
-        h, _ = moe_apply(blk["moe"], h, moe_cfg(cfg))
-        if collect_stats:
-            stats = SM.zero_mlp_stats((b,), cfg.sparse.tp_shards)
-    else:
-        al = jnp.asarray(alpha, jnp.float32)
-        if al.ndim == 1:                                   # per-slot (B,)
-            al = al[:, None]
-        a_tok = jnp.where(tok_mask, al, SM.DEAD_SLOT_ALPHA).reshape(-1)
-        if collect_stats:
-            h, st = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
-                              prefill=True, alpha=a_tok, return_stats=True)
-            stats = jax.tree.map(wmean, st)
+    with jax.named_scope("mlp"):
+        h = C.norm_apply(cfg, blk["ln2"], x)
+        if "moe" in blk:
+            h, _ = moe_apply(blk["moe"], h, moe_cfg(cfg))
+            if collect_stats:
+                stats = SM.zero_mlp_stats((b,), cfg.sparse.tp_shards)
         else:
-            h = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
-                          prefill=True, alpha=a_tok)
-    if cfg.post_block_norm:
-        h = C.norm_apply(cfg, blk["ln2_post"], h)
+            al = jnp.asarray(alpha, jnp.float32)
+            if al.ndim == 1:                               # per-slot (B,)
+                al = al[:, None]
+            a_tok = jnp.where(tok_mask, al, SM.DEAD_SLOT_ALPHA).reshape(-1)
+            if collect_stats:
+                h, st = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
+                                  prefill=True, alpha=a_tok,
+                                  return_stats=True)
+                stats = jax.tree.map(wmean, st)
+            else:
+                h = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
+                              prefill=True, alpha=a_tok)
+        if cfg.post_block_norm:
+            h = C.norm_apply(cfg, blk["ln2_post"], h)
     x = R.shard_activations(x + h, sp=cfg.sp_activations)
     return x, cache, stats
 
@@ -819,7 +828,8 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jax.Array,
         raise NotImplementedError(
             f"chunked prefill supports dense/moe, not {cfg.family!r}")
     tokens = R.shard_tokens(tokens)
-    x = _embed_in(params, cfg, tokens)
+    with jax.named_scope("embed"):
+        x = _embed_in(params, cfg, tokens)
     b, s = tokens.shape
     off = jnp.asarray(offset, jnp.int32)
     vld = jnp.asarray(valid, jnp.int32)
@@ -829,10 +839,11 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens: jax.Array,
     tok_mask = pos[None, :] < vld[:, None]                    # (B, S)
     x, caches, stats = _dense_stack_chunk(params, x, cfg, caches, off, vld,
                                           tok_mask, alphas, collect_stats)
-    x = C.norm_apply(cfg, params["final_norm"], x)
-    last = jnp.clip(vld - 1 - off, 0, s - 1)                  # (B,)
-    xl = x[jnp.arange(b), last]
-    logits = C.head_logits(xl, _head_table(params), cfg.final_softcap)
+    with jax.named_scope("head"):
+        x = C.norm_apply(cfg, params["final_norm"], x)
+        last = jnp.clip(vld - 1 - off, 0, s - 1)              # (B,)
+        xl = x[jnp.arange(b), last]
+        logits = C.head_logits(xl, _head_table(params), cfg.final_softcap)
     if collect_stats:
         return logits, caches, stats
     return logits, caches
@@ -916,7 +927,8 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
     ``SHARD_STAT_KEY`` shaped (L, B, tp_shards).  Results are bitwise
     identical to the single-device emulation of the same config.
     """
-    x = _embed_in(params, cfg, token)
+    with jax.named_scope("embed"):
+        x = _embed_in(params, cfg, token)
     stats = None
     if block_table is not None and cfg.family not in PAGED_KV_FAMILIES:
         raise NotImplementedError(
@@ -933,8 +945,10 @@ def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
         x, caches = _xlstm_decode(params, x, cfg, caches)
     else:
         raise ValueError(cfg.family)
-    x = C.norm_apply(cfg, params["final_norm"], x)
-    logits = C.head_logits(x[:, 0], _head_table(params), cfg.final_softcap)
+    with jax.named_scope("head"):
+        x = C.norm_apply(cfg, params["final_norm"], x)
+        logits = C.head_logits(x[:, 0], _head_table(params),
+                               cfg.final_softcap)
     if collect_stats:
         return logits, caches, stats
     return logits, caches

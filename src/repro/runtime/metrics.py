@@ -13,12 +13,15 @@ registry everything emits into:
   (the percentile then reports the covering bucket's upper bound — a
   conservative estimate whose error is bounded by the bucket width).
 
-* **Span-style phase tracing.**  ``span()`` stamps admission → prefill
-  chunk → decode step → preemption/shed → controller update phases from
-  *the same clock the scheduler uses* — wall clock, or the ``FaultInjector``
-  virtual clock when one is armed (``bind_clock``) — and exports them as
-  Chrome/Perfetto ``trace_event`` JSON (``trace_events`` /
-  ``write_trace``), one ``tid`` row per phase name.
+* **Spans on the profiler clock.**  ``span()`` is the one span API: it
+  opens a ``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation``
+  when given ``step_num``) whether the hub is enabled or not, so every
+  serve phase lands in a ``jax.profiler`` capture on the same clock as
+  the device's ops and transfers.  With no profiler session active a
+  span costs about a microsecond.  When the hub is enabled a span given
+  ``hist`` also folds its duration into that histogram, stamped from
+  *the scheduler's clock* — wall clock, or the ``FaultInjector`` virtual
+  clock when one is armed (``bind_clock``).
 
 * **Structured sinks.**  A JSONL event stream (``event()``; every line is
   ``{"ts": float, "kind": str, ...}`` — :func:`validate_jsonl` is the
@@ -39,8 +42,9 @@ registry everything emits into:
 **Overhead contract.**  Emission is plain Python over already-materialized
 host values: no extra device syncs, no new jit inputs, zero retraces
 (pinned by tests/test_metrics.py).  A disabled hub is a no-op — every
-public method returns immediately (``span`` hands back a cached null
-context), so the serve loop is bitwise-identical with the hub on or off.
+public method returns immediately (``span`` hands back a bare profiler
+annotation), so the serve loop is bitwise-identical with the hub on or
+off.
 """
 from __future__ import annotations
 
@@ -52,6 +56,8 @@ import math
 import time
 import warnings
 from typing import Any, Callable, Optional
+
+import jax
 
 from repro.configs.base import MetricsConfig
 
@@ -157,61 +163,43 @@ class Histogram:
                 "exact": self.exact}
 
 
-class _NullSpan:
-    """Cached no-op context manager for the disabled-hub fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class _Span:
-    """One timed phase: stamps enter/exit from the hub's clock, appends a
-    Chrome ``"ph": "X"`` trace event (when tracing is on) and optionally
-    folds the duration into a histogram (``hist``)."""
+    """A profiler annotation that also times its phase on the hub's clock
+    and folds the duration into the histogram ``hist``."""
 
-    __slots__ = ("hub", "name", "labels", "hist", "t0", "dur")
+    __slots__ = ("hub", "ann", "labels", "hist", "t0")
 
-    def __init__(self, hub: "MetricsHub", name: str, hist: Optional[str],
-                 labels: dict):
+    def __init__(self, hub: "MetricsHub", ann, hist: str, labels: dict):
         self.hub = hub
-        self.name = name
+        self.ann = ann
         self.labels = labels
         self.hist = hist
         self.t0 = 0.0
-        self.dur = 0.0
 
     def __enter__(self):
+        self.ann.__enter__()
         self.t0 = self.hub.now()
         return self
 
     def __exit__(self, *exc):
-        t1 = self.hub.now()
-        self.dur = max(0.0, t1 - self.t0)
-        if self.hist is not None:
-            self.hub.observe(self.hist, self.dur, **self.labels)
-        self.hub._trace_complete(self.name, self.t0, self.dur, self.labels)
-        return False
+        dur = max(0.0, self.hub.now() - self.t0)
+        self.hub.observe(self.hist, dur, **self.labels)
+        return self.ann.__exit__(*exc)
 
 
 class MetricsHub:
-    """Registry of counters/gauges/histograms + trace and JSONL sinks.
+    """Registry of counters/gauges/histograms + JSONL sink, and the span
+    API.
 
     Construct with a ``configs.base.MetricsConfig`` (``enabled=False`` —
-    the default — makes every method a no-op) and drive through the
-    instrument methods; ``bind_clock`` points the hub at the scheduler's
-    clock so spans and events share its notion of time (virtual under a
-    ``FaultInjector``).  Exports: :meth:`snapshot` (JSON-friendly dict),
-    :meth:`exposition` (Prometheus text), :meth:`trace_events`
-    (Chrome/Perfetto), :meth:`events` (JSONL ring), :meth:`flush`
-    (write configured sink files)."""
+    the default — makes every instrument a no-op; spans stay profiler
+    annotations) and drive through the instrument methods; ``bind_clock``
+    points the hub at the scheduler's clock so span histograms and events
+    share its notion of time (virtual under a ``FaultInjector``).
+    Exports: :meth:`snapshot` (JSON-friendly dict), :meth:`exposition`
+    (Prometheus text), :meth:`events` (JSONL ring), :meth:`flush` (write
+    configured sink files).  Spans go to the ``jax.profiler`` trace
+    (:func:`capture`)."""
 
     def __init__(self, cfg: Optional[MetricsConfig] = None,
                  clock: Optional[Callable[[], float]] = None):
@@ -225,17 +213,12 @@ class MetricsHub:
                 f"{cfg.hist_max_exact}/{cfg.events_keep}")
         self.cfg = cfg
         self.enabled = bool(cfg.enabled)
-        self._trace_on = self.enabled and (cfg.trace or bool(cfg.trace_path))
         self._clock: Callable[[], float] = clock or time.perf_counter
-        self._t0: Optional[float] = None      # trace-timestamp origin
         self._counters: dict = {}
         self._gauges: dict = {}
         self._hists: dict = {}
         self._events: collections.deque = collections.deque(
             maxlen=cfg.events_keep)
-        self._trace: collections.deque = collections.deque(
-            maxlen=cfg.events_keep)
-        self._tids: dict = {}                  # phase name -> trace row
         self._jsonl = None                     # lazy append handle
         self.watchdog = RetraceWatchdog(self)
         if self.enabled and cfg.watchdog:
@@ -243,19 +226,13 @@ class MetricsHub:
 
     # ------------------------------------------------------------- clock --
     def bind_clock(self, clock: Callable[[], float]) -> None:
-        """Point the hub at the scheduler's clock (``Server._now``): spans,
-        events and trace timestamps then share the scheduler's notion of
-        time — the ``FaultInjector`` virtual clock when one is armed."""
+        """Point the hub at the scheduler's clock (``Server._now``): span
+        histograms and events then share the scheduler's notion of time —
+        the ``FaultInjector`` virtual clock when one is armed."""
         self._clock = clock
 
     def now(self) -> float:
         return float(self._clock())
-
-    def _us(self, t: float) -> float:
-        """Trace timestamp in microseconds relative to the first stamp."""
-        if self._t0 is None:
-            self._t0 = t
-        return (t - self._t0) * 1e6
 
     # -------------------------------------------------------- instruments --
     def inc(self, name: str, value: float = 1, **labels) -> float:
@@ -310,50 +287,20 @@ class MetricsHub:
         h = self._hists.get((name, _label_key(labels)))
         return h.count if h is not None else 0
 
-    # ------------------------------------------------------------ tracing --
+    # -------------------------------------------------------------- spans --
     def span(self, name: str, hist: Optional[str] = None, **labels):
-        """Timed phase context: ``with hub.span("decode_step",
-        hist="decode_step_s", step=i): ...``.  Disabled — or enabled with
-        tracing off and no ``hist`` — it is the cached null context (no
-        clock reads at all)."""
-        if not self.enabled or (hist is None and not self._trace_on):
-            return _NULL_SPAN
-        return _Span(self, name, hist, labels)
-
-    def complete(self, name: str, t0: float, hist: Optional[str] = None,
-                 **labels) -> None:
-        """Record a phase that started at ``t0`` (a prior ``now()`` stamp)
-        and ends now — the non-context-manager twin of :meth:`span`, for
-        phases whose start/end straddle control flow (the decode step)."""
-        if not self.enabled:
-            return
-        dur = max(0.0, self.now() - t0)
-        if hist is not None:
-            self.observe(hist, dur, **labels)
-        self._trace_complete(name, t0, dur, labels)
-
-    def _trace_complete(self, name: str, t0: float, dur: float,
-                        labels: dict) -> None:
-        if not self._trace_on:
-            return
-        tid = self._tids.setdefault(name, len(self._tids) + 1)
-        self._trace.append({"name": name, "cat": "serve", "ph": "X",
-                            "ts": self._us(t0), "dur": dur * 1e6,
-                            "pid": 0, "tid": tid,
-                            "args": {k: _jsonable(v)
-                                     for k, v in labels.items()}})
-
-    def instant(self, name: str, **labels) -> None:
-        """Zero-duration trace marker (sheds, preemptions, bucket
-        switches)."""
-        if not self._trace_on:
-            return
-        tid = self._tids.setdefault(name, len(self._tids) + 1)
-        self._trace.append({"name": name, "cat": "serve", "ph": "i",
-                            "ts": self._us(self.now()), "pid": 0,
-                            "tid": tid, "s": "t",
-                            "args": {k: _jsonable(v)
-                                     for k, v in labels.items()}})
+        """Phase context on the profiler's clock: ``with hub.span(
+        "serve.decode", hist="decode_step_s"): ...``.  Always a
+        ``jax.profiler.TraceAnnotation(name, **labels)`` — a
+        ``StepTraceAnnotation`` when ``labels`` holds ``step_num`` — so a
+        profiler capture shows it whether the hub is enabled or not.  With
+        the hub enabled and ``hist`` given, the phase's duration on the
+        hub's clock is also observed into ``hist`` (with ``labels``)."""
+        ann = (jax.profiler.StepTraceAnnotation if "step_num" in labels
+               else jax.profiler.TraceAnnotation)(name, **labels)
+        if hist is None or not self.enabled:
+            return ann
+        return _Span(self, ann, hist, labels)
 
     # -------------------------------------------------------- JSONL events --
     def event(self, kind: str, **payload) -> None:
@@ -419,21 +366,6 @@ class MetricsHub:
         lines.append(f"{fam} {self.watchdog.retraces_post_warmup}")
         return "\n".join(lines) + "\n"
 
-    def trace_events(self) -> dict:
-        """Chrome/Perfetto ``trace_event`` JSON object (load in
-        ``chrome://tracing`` or ui.perfetto.dev)."""
-        return {"traceEvents": list(self._trace),
-                "displayTimeUnit": "ms",
-                "otherData": {"source": "repro.runtime.metrics"}}
-
-    def write_trace(self, path: Optional[str] = None) -> Optional[str]:
-        path = path or self.cfg.trace_path
-        if not path:
-            return None
-        with open(path, "w") as f:
-            json.dump(self.trace_events(), f)
-        return path
-
     def write_snapshot(self, path: Optional[str] = None) -> Optional[str]:
         path = path or self.cfg.snapshot_path
         if not path:
@@ -443,13 +375,12 @@ class MetricsHub:
         return path
 
     def flush(self) -> None:
-        """Flush the JSONL handle and write the configured trace/exposition
-        sink files (serve-drain boundary)."""
+        """Flush the JSONL handle and write the configured exposition sink
+        file (serve-drain boundary)."""
         if not self.enabled:
             return
         if self._jsonl is not None:
             self._jsonl.flush()
-        self.write_trace()
         self.write_snapshot()
 
     def close(self) -> None:
@@ -515,6 +446,16 @@ def validate_jsonl(path: str, max_lines: int = 0) -> int:
     if n == 0:
         raise ValueError(f"{path}: no JSONL records")
     return n
+
+
+def capture(log_dir: str):
+    """A ``jax.profiler`` capture into ``log_dir`` (a null context when it
+    is empty): the serve spans, the model step's device ops and the
+    transfers on one clock, with a Perfetto trace (``perfetto_trace.json.gz``)
+    written beside the ``.xplane.pb``."""
+    if not log_dir:
+        return contextlib.nullcontext()
+    return jax.profiler.trace(log_dir, create_perfetto_trace=True)
 
 
 # ---------------------------------------------------------------------------
@@ -595,7 +536,6 @@ class RetraceWatchdog:
         if hub is not None and hub.enabled:
             hub.inc("retrace_post_warmup")
             hub.event("retrace", n=self.retraces_post_warmup)
-            hub.instant("retrace", n=self.retraces_post_warmup)
         warnings.warn(
             "post-warmup retrace detected: a jitted function traced after "
             "the serve warmup boundary — the zero-retrace serving "

@@ -750,7 +750,6 @@ class Server:
         if hub.enabled:
             rep["snapshot"] = hub.snapshot()
             rep["events"] = len(hub.events())
-            rep["trace_events"] = len(hub.trace_events()["traceEvents"])
         return rep
 
     @property
@@ -1050,7 +1049,8 @@ class Server:
         per-tier aggregation when tiered, masked batch mean otherwise
         (``active`` None means every slot is live — generate())."""
         ctl = self.controller
-        stats = {k: np.asarray(v) for k, v in stats.items()}
+        with self.metrics.span("serve.sync"):
+            stats = {k: np.asarray(v) for k, v in stats.items()}
         if isinstance(ctl, DistributedController):
             # strip (and, off-audit, fold into the skew EMAs) the per-shard
             # rider before the (L, B) aggregation paths see the dict
@@ -1359,7 +1359,6 @@ class Server:
             hub.inc("requests_shed", reason=reason)
             hub.event("shed", uid=r.uid, tier=r.sla, reason=reason,
                       tokens=len(r.out))
-            hub.instant("shed", uid=r.uid, reason=reason)
             done.append(r)
 
         def _clear_slot(i: int) -> None:
@@ -1400,7 +1399,6 @@ class Server:
             hub.inc("preemptions", tier=r.sla)
             hub.event("preempt", uid=r.uid, tier=r.sla,
                       preemptions=r.preemptions)
-            hub.instant("preempt", uid=r.uid)
             _clear_slot(i)
             queue.append(r)
 
@@ -1574,21 +1572,9 @@ class Server:
             return True
 
         def admit(i: int) -> None:
-            """Fill slot i from the queue — traced as one "admission" span
-            per attempt (dequeue through placement/pending, including any
-            expired-at-dequeue sheds along the way)."""
-            if not queue:
-                return
-            with hub.span("admission", slot=i):
-                _admit(i)
-
-        def _admit(i: int) -> None:
-            """Fill slot i from the queue.  With chunked prefill the slot
-            goes PENDING (scratch caches; chunks advance interleaved with
-            decode steps); otherwise the monolithic batch-1 prefill runs at
-            the prompt's natural length -> exact single-request semantics,
-            one trace per distinct prompt length."""
-            nonlocal caches
+            """Fill slot i from the queue, one "serve.admit" span per
+            request dequeued (through placement or pending, an
+            expired-at-dequeue shed included)."""
             while queue:
                 if pool_wait[0]:
                     return        # exhaustion latch: wait for a release
@@ -1602,75 +1588,87 @@ class Server:
                     self.admissions_deferred += 1
                     return
                 r = queue.popleft()
-                if _expired(r, self._now()):
-                    _shed(r, "deadline")  # expired while queued
-                    continue
+                with hub.span("serve.admit", uid=r.uid, slot=i):
+                    if not _admit_one(i, r):
+                        return
+
+        def _admit_one(i: int, r: Request) -> bool:
+            """Admit the dequeued ``r`` into slot i; True when the slot is
+            still free and admission should go on to the next request.
+            With chunked prefill the slot goes PENDING (scratch caches;
+            chunks advance interleaved with decode steps); otherwise the
+            monolithic batch-1 prefill runs at the prompt's natural length
+            -> exact single-request semantics, one trace per distinct
+            prompt length."""
+            if _expired(r, self._now()):
+                _shed(r, "deadline")  # expired while queued
+                return True
+            if paged:
+                sess = pool_mgr.lookup_session(r.session_id)
+                if sess is not None:
+                    # session-sticky SLA: the stored tier binds every
+                    # turn of the conversation to one point on the
+                    # accuracy/sparsity curve (and, under a per-tier
+                    # controller, to one adapted alpha vector) — the
+                    # per-session controller state (DESIGN.md §10)
+                    r.sla = sess["tier"]
+            t = self._tier_of(r)      # queue pre-validated in serve()
+            plen = len(r.prompt)
+            now = self._now()
+            r.t_start = now           # dequeue: service starts
+            r.queue_wait_s = now - r.t_admit if r.t_admit else 0.0
+            hub.observe("queue_wait_s", r.queue_wait_s, tier=r.sla)
+            hub.event("admit", uid=r.uid, tier=r.sla, plen=plen,
+                      queue_wait_s=r.queue_wait_s)
+            if self._chunk_prefill:
+                pc = self.scfg.prefill_chunk
+                padded = -(-plen // pc) * pc
+                toks = np.zeros((1, padded), np.int32)
+                toks[0, :plen] = np.asarray(r.prompt, np.int32)
+                st = {
+                    "req": r, "tier": t, "tokens": toks, "off": 0,
+                    "plen": plen,
+                    "caches": self.mod.init_caches(self.cfg, 1,
+                                                   scfg.max_len),
+                    "extra": self._slot_extra(i, extra),
+                }
                 if paged:
-                    sess = pool_mgr.lookup_session(r.session_id)
-                    if sess is not None:
-                        # session-sticky SLA: the stored tier binds every
-                        # turn of the conversation to one point on the
-                        # accuracy/sparsity curve (and, under a per-tier
-                        # controller, to one adapted alpha vector) — the
-                        # per-session controller state (DESIGN.md §10)
-                        r.sla = sess["tier"]
-                t = self._tier_of(r)      # queue pre-validated in serve()
-                plen = len(r.prompt)
-                now = self._now()
-                r.t_start = now           # dequeue: service starts
-                r.queue_wait_s = now - r.t_admit if r.t_admit else 0.0
-                hub.observe("queue_wait_s", r.queue_wait_s, tier=r.sla)
-                hub.event("admit", uid=r.uid, tier=r.sla, plen=plen,
-                          queue_wait_s=r.queue_wait_s)
-                if self._chunk_prefill:
-                    pc = self.scfg.prefill_chunk
-                    padded = -(-plen // pc) * pc
-                    toks = np.zeros((1, padded), np.int32)
-                    toks[0, :plen] = np.asarray(r.prompt, np.int32)
-                    st = {
-                        "req": r, "tier": t, "tokens": toks, "off": 0,
-                        "plen": plen,
-                        "caches": self.mod.init_caches(self.cfg, 1,
-                                                       scfg.max_len),
-                        "extra": self._slot_extra(i, extra),
-                    }
-                    if paged:
-                        st["meta"] = self._match_reuse(r, t, plen)
-                        m = st["meta"]
-                        if m["adopted"]:
-                            # admit by reference: seed the scratch with the
-                            # adopted blocks and start chunking at the
-                            # reuse boundary — the skipped chunks' work is
-                            # exactly what the pool already holds
-                            st["off"] = m["adopted"] * bs_
-                            seed = np.zeros(nbps, np.int32)   # NULL lanes
-                            seed[:m["adopted"]] = m["ids"][:m["adopted"]]
-                            st["caches"] = self.seed_fn(caches,
-                                                        jnp.asarray(seed))
-                            self.prefill_chunks_skipped += st["off"] // pc
-                    pending[i] = st
-                    return
-                prompt = jnp.asarray(
-                    np.asarray(r.prompt, np.int32)[None, :])
-                ex = tuple(e[i:i + 1] for e in extra)
-                try:
-                    self._fault("prefill", r.uid)
-                    with hub.span("prefill", hist="prefill_s", slot=i,
-                                  uid=r.uid):
-                        logits, one = self.prefill_fn(self.params, prompt,
-                                                      *ex)
-                except InjectedFault:
-                    _shed(r, "fault")     # injected slot death mid-prefill
-                    continue
-                first = int(np.asarray(greedy_sample(logits))[0])
-                if not place(i, r, first, plen, t, one):
-                    if pool_wait[0]:
-                        return    # backpressure latched: stop admitting
-                    continue      # shed on pool exhaustion; try the next
-                if r.max_new <= 1:
-                    finish(i)     # prefill alone satisfied it; keep draining
-                    continue
-                return
+                    st["meta"] = self._match_reuse(r, t, plen)
+                    m = st["meta"]
+                    if m["adopted"]:
+                        # admit by reference: seed the scratch with the
+                        # adopted blocks and start chunking at the
+                        # reuse boundary — the skipped chunks' work is
+                        # exactly what the pool already holds
+                        st["off"] = m["adopted"] * bs_
+                        seed = np.zeros(nbps, np.int32)   # NULL lanes
+                        seed[:m["adopted"]] = m["ids"][:m["adopted"]]
+                        st["caches"] = self.seed_fn(caches,
+                                                    jnp.asarray(seed))
+                        self.prefill_chunks_skipped += st["off"] // pc
+                pending[i] = st
+                return False
+            prompt = jnp.asarray(
+                np.asarray(r.prompt, np.int32)[None, :])
+            ex = tuple(e[i:i + 1] for e in extra)
+            try:
+                self._fault("prefill", r.uid)
+            except InjectedFault:
+                _shed(r, "fault")     # injected slot death mid-prefill
+                return True
+            with hub.span("serve.prefill", hist="prefill_s", slot=i,
+                          uid=r.uid):
+                logits, one = self.prefill_fn(self.params, prompt, *ex)
+                with hub.span("serve.sync"):
+                    first = int(np.asarray(greedy_sample(logits))[0])
+            if not place(i, r, first, plen, t, one):
+                # shed on pool exhaustion: try the next, unless
+                # backpressure latched
+                return not pool_wait[0]
+            if r.max_new <= 1:
+                finish(i)     # prefill alone satisfied it; keep draining
+                return True
+            return False
 
         def advance_prefill(budget: int) -> None:
             """Run up to ``budget`` prefill chunks (round-robin over pending
@@ -1692,37 +1690,45 @@ class Server:
                         _kill_pending(i, "fault")
                         admit(i)
                         continue
-                    chunk_toks = jnp.asarray(
-                        st["tokens"][:, st["off"]:st["off"] + pc])
-                    al = jnp.asarray(self._prefill_alphas(st["tier"]))
-                    fn = (self.prefill_chunk_stats_fn if prefill_stats
-                          else self.prefill_chunk_fn)
-                    with hub.span("prefill_chunk", hist="prefill_chunk_s",
-                                  slot=i, uid=r.uid):
-                        out = fn(self.params, chunk_toks, st["caches"],
-                                 jnp.int32(st["off"]), jnp.int32(st["plen"]),
-                                 al, *st["extra"])
-                    if prefill_stats:
-                        logits, st["caches"], stats = out
-                        ctl.observe_prefill(
-                            {k: np.asarray(v)[:, 0]
-                             for k, v in stats.items()},
-                            tier=st["tier"] if ctl.tiers else None)
-                    else:
-                        logits, st["caches"] = out
-                    st["off"] += pc
+                    with hub.span("serve.prefill_chunk",
+                                  hist="prefill_chunk_s", slot=i,
+                                  uid=r.uid):
+                        _prefill_chunk(i, st, r, pc)
                     budget -= 1
-                    self.prefill_chunks_run += 1
-                    if st["off"] >= st["tokens"].shape[1]:
-                        first = int(np.asarray(greedy_sample(logits))[0])
-                        del pending[i]
-                        if not place(i, r, first, st["plen"], st["tier"],
-                                     st["caches"], meta=st.get("meta")):
-                            admit(i)   # requeued/shed; admit() no-ops
-                            #            while the exhaustion latch holds
-                        elif r.max_new <= 1:
-                            finish(i)
-                            admit(i)   # refill: may re-enter pending
+
+        def _prefill_chunk(i: int, st: dict, r: Request, pc: int) -> None:
+            """Slice, transfer and dispatch slot i's next chunk; after the
+            last one, wait for its first token and place the request."""
+            chunk_toks = jnp.asarray(
+                st["tokens"][:, st["off"]:st["off"] + pc])
+            al = jnp.asarray(self._prefill_alphas(st["tier"]))
+            fn = (self.prefill_chunk_stats_fn if prefill_stats
+                  else self.prefill_chunk_fn)
+            out = fn(self.params, chunk_toks, st["caches"],
+                     jnp.int32(st["off"]), jnp.int32(st["plen"]),
+                     al, *st["extra"])
+            if prefill_stats:
+                logits, st["caches"], stats = out
+                with hub.span("serve.sync"):
+                    stats = {k: np.asarray(v)[:, 0]
+                             for k, v in stats.items()}
+                ctl.observe_prefill(stats,
+                                    tier=st["tier"] if ctl.tiers else None)
+            else:
+                logits, st["caches"] = out
+            st["off"] += pc
+            self.prefill_chunks_run += 1
+            if st["off"] >= st["tokens"].shape[1]:
+                with hub.span("serve.sync"):
+                    first = int(np.asarray(greedy_sample(logits))[0])
+                del pending[i]
+                if not place(i, r, first, st["plen"], st["tier"],
+                             st["caches"], meta=st.get("meta")):
+                    admit(i)   # requeued/shed; admit() no-ops
+                    #            while the exhaustion latch holds
+                elif r.max_new <= 1:
+                    finish(i)
+                    admit(i)   # refill: may re-enter pending
 
         def ensure_write_blocks() -> None:
             """Before a decode step, every live slot's write position
@@ -1745,22 +1751,9 @@ class Server:
                             raise     # legacy hard failure preserved
                         _relieve(exclude=i)  # slot i active => a victim exists
 
-        for i in range(B):
-            admit(i)
-        if (ctl is not None and scfg.warm_buckets
-                and not self._warmed_buckets and active.any()):
-            if paged:
-                ensure_write_blocks()
-            self._warm_bucket_ladder(tok, caches, lengths,
-                              self._slot_alpha_matrix(tier_idx, active),
-                              table=table if paged else None)
-        # queue can be non-empty with every slot idle (admissions deferred
-        # by the pressure gate, or slots freed by shed/preempt): the loop
-        # runs until all three drain.  Each iteration either decodes,
-        # prefills, admits, or sheds — and the virtual clock ticks
-        # regardless — so it always terminates.
-        step_n = 0                    # decode steps (gauge-publish cadence)
-        while active.any() or pending or queue:
+        def schedule() -> None:
+            """One pass's scheduling: deadline sheds, deadline-pressure
+            preemption and the refill of empty slots."""
             self._tick()
             now = self._now()
             # deadline enforcement (DESIGN.md §11): resident and
@@ -1788,86 +1781,67 @@ class Server:
                                                   len(slot_out[j]), j)))
             # refill empty slots: covers deferred admissions retrying as
             # pressure drops, and slots freed by shed/preempt above (the
-            # post-decode refill below covers normal completions)
+            # post-decode refill in commit covers normal completions)
             if queue and not pool_wait[0]:
                 for i in range(B):
                     if slot_req[i] is None and i not in pending:
                         admit(i)
-            if pending:
-                # interleave admissions with decode: ≤ prefill_interleave
-                # chunks per iteration so a long admission never stalls the
-                # resident requests for its whole prompt (DESIGN.md §9)
-                advance_prefill(scfg.prefill_interleave)
-                if not active.any():
-                    continue     # nothing decoding yet — keep prefilling
-            if not active.any():
-                continue         # deferred/shed everything this pass
-            if paged:
-                ensure_write_blocks()
-                if not active.any():
-                    continue     # exhaustion relief preempted every slot
-            self._fault("decode")   # armed decode faults are FATAL: they
-            #                         abort serve() and exercise reset()
-            t_dec = hub.now() if hub.enabled else 0.0
+
+        def decode() -> np.ndarray:
+            """One decode step over every slot: the controller's bucket
+            choice, the slot inputs' transfer, the dispatch, the
+            controller's update, and the wait for the step's tokens."""
+            nonlocal caches, alpha_mat
             if ctl is not None:
-                audit = ctl.is_audit_step()
-                # between-step capacity-bucket switch: a host dict lookup
-                # into the pre-jitted (per-shard tuple) ladder — never a
-                # retrace
-                prev_cap = self._active_cap
-                self._select_bucket()
-                if hub.enabled and self._active_cap != prev_cap:
-                    hub.inc("bucket_switches")
-                    hub.event("bucket_switch", bucket=self._active_cap)
-                    hub.instant("bucket_switch")
-                fn = self.decode_audit_fn if audit else self.decode_ctrl_fn
-                # rebuilt per step: the controller adapts between steps
-                alphas = self._slot_alpha_matrix(tier_idx, active)
-                if paged:
-                    jt, jl, ja, jtab = self._put_slots(tok, lengths, alphas,
-                                                       table)
-                    ntok, caches, stats = fn(self.params, jt, caches, jl,
-                                             ja, jtab)
+                with hub.span("serve.controller"):
+                    audit = ctl.is_audit_step()
+                    # between-step capacity-bucket switch: a host dict
+                    # lookup into the pre-jitted (per-shard tuple) ladder —
+                    # never a retrace
+                    prev_cap = self._active_cap
+                    self._select_bucket()
+                    if hub.enabled and self._active_cap != prev_cap:
+                        hub.inc("bucket_switches")
+                        hub.event("bucket_switch", bucket=self._active_cap)
+                    fn = self.decode_audit_fn if audit else self.decode_ctrl_fn
+            with hub.span("serve.put_slots"):
+                if ctl is not None:
+                    # rebuilt per step: the controller adapts between steps
+                    alphas = self._slot_alpha_matrix(tier_idx, active)
+                elif legacy and active.all():
+                    # uniform schedule, every slot live: the seed decode jit
+                    # (bit-identical path; no alpha plumbing at all)
+                    alphas = None
                 else:
-                    jt, jl, ja = self._put_slots(tok, lengths, alphas)
-                    ntok, caches, stats = fn(self.params, jt, caches, jl, ja)
-                with hub.span("controller_update",
+                    # static alphas change only at refill boundaries — cache
+                    # the matrix; dead slots are neutralized out of the union
+                    if alpha_mat is None:
+                        alpha_mat = self._slot_alpha_matrix(tier_idx, active)
+                    alphas = alpha_mat
+                # tab: the block table, paged only
+                jt, jl, ja, *tab = self._put_slots(tok, lengths, alphas,
+                                                   table)
+            with hub.span("serve.decode_dispatch"):
+                if ctl is not None:
+                    ntok, caches, stats = fn(self.params, jt, caches, jl, ja,
+                                             *tab)
+                elif alphas is None:
+                    ntok, caches = self.decode_fn(self.params, jt, caches,
+                                                  jl, *tab)
+                else:
+                    ntok, caches = self.decode_alpha_fn(
+                        self.params, jt, caches, jl, ja, *tab)
+            if ctl is not None:
+                with hub.span("serve.controller",
                               hist="controller_update_s"):
                     self._observe_step(stats, tier_idx, active, audit)
-            elif legacy and active.all():
-                # uniform schedule, every slot live: the seed decode jit
-                # (bit-identical path; no alpha plumbing at all)
-                if paged:
-                    jt, jl, _, jtab = self._put_slots(tok, lengths,
-                                                      table=table)
-                    ntok, caches = self.decode_fn(self.params, jt, caches,
-                                                  jl, jtab)
-                else:
-                    jt, jl, _ = self._put_slots(tok, lengths)
-                    ntok, caches = self.decode_fn(self.params, jt, caches,
-                                                  jl)
-            else:
-                # static alphas change only at refill boundaries — cache the
-                # matrix; dead slots are neutralized out of the union
-                if alpha_mat is None:
-                    alpha_mat = self._slot_alpha_matrix(tier_idx, active)
-                if paged:
-                    jt, jl, ja, jtab = self._put_slots(tok, lengths,
-                                                       alpha_mat, table)
-                    ntok, caches = self.decode_alpha_fn(
-                        self.params, jt, caches, jl, ja, jtab)
-                else:
-                    jt, jl, ja = self._put_slots(tok, lengths, alpha_mat)
-                    ntok, caches = self.decode_alpha_fn(
-                        self.params, jt, caches, jl, ja)
-            ntok = np.asarray(ntok)
-            # the decode phase ends at host materialization (np.asarray
-            # blocks on the step's device work); under the virtual clock
-            # the span is 0-duration and purely structural
-            hub.complete("decode_step", t_dec, hist="decode_step_s")
-            step_n += 1
-            if hub.enabled and step_n % scfg.metrics.cadence == 0:
-                self.publish_gauges()
+            with hub.span("serve.sync"):
+                return np.asarray(ntok)
+
+        def commit(ntok: np.ndarray) -> None:
+            """Append the step's tokens, finish the slots that are done
+            and refill them from the queue."""
+            nonlocal alpha_mat
             refill = []
             for i in range(B):
                 if not active[i]:
@@ -1884,6 +1858,62 @@ class Server:
                     self.maybe_adapt_capacity()  # re-jit (DESIGN.md §4)
                     for i in refill:
                         admit(i)
+
+        # Every pass of the loop is one "serve.iteration" profiler step
+        # (pass 0: the initial fill); its children cover the pass's host
+        # work, and "serve.sync" every wait on device results, so a
+        # profiler capture puts each device idle gap down to a phase.
+        it = 0
+        with hub.span("serve.iteration", step_num=it), \
+                hub.span("serve.schedule"):
+            for i in range(B):
+                admit(i)
+            if (ctl is not None and scfg.warm_buckets
+                    and not self._warmed_buckets and active.any()):
+                if paged:
+                    ensure_write_blocks()
+                self._warm_bucket_ladder(
+                    tok, caches, lengths,
+                    self._slot_alpha_matrix(tier_idx, active),
+                    table=table if paged else None)
+        # queue can be non-empty with every slot idle (admissions deferred
+        # by the pressure gate, or slots freed by shed/preempt): the loop
+        # runs until all three drain.  Each iteration either decodes,
+        # prefills, admits, or sheds — and the virtual clock ticks
+        # regardless — so it always terminates.
+        step_n = 0                    # decode steps (gauge-publish cadence)
+        while active.any() or pending or queue:
+            it += 1
+            with hub.span("serve.iteration", step_num=it):
+                with hub.span("serve.schedule"):
+                    schedule()
+                if pending:
+                    # interleave admissions with decode: ≤
+                    # prefill_interleave chunks per iteration so a long
+                    # admission never stalls the resident requests for its
+                    # whole prompt (DESIGN.md §9)
+                    advance_prefill(scfg.prefill_interleave)
+                    if not active.any():
+                        continue     # nothing decoding yet — keep prefilling
+                if not active.any():
+                    continue         # deferred/shed everything this pass
+                if paged:
+                    with hub.span("serve.schedule"):
+                        ensure_write_blocks()
+                    if not active.any():
+                        continue     # exhaustion relief preempted every slot
+                self._fault("decode")   # armed decode faults are FATAL: they
+                #                         abort serve() and exercise reset()
+                # the decode phase ends at host materialization (np.asarray
+                # blocks on the step's device work); under the virtual clock
+                # the span is 0-duration and purely structural
+                with hub.span("serve.decode", hist="decode_step_s"):
+                    ntok = decode()
+                with hub.span("serve.commit"):
+                    step_n += 1
+                    if hub.enabled and step_n % scfg.metrics.cadence == 0:
+                        self.publish_gauges()
+                    commit(ntok)
         if paged:
             # the pool outlives the drain: sessions + committed prefixes
             # admit by reference in later serve() calls (DESIGN.md §10)

@@ -3,23 +3,25 @@
 Unit level: counter/gauge/histogram semantics (exact-then-bucketed
 percentiles, label keying), the shared nearest-rank percentile helper's
 parity with the two implementations it replaced, JSONL/exposition golden
-shapes, trace-event well-formedness, and the retrace watchdog firing on a
-forced recompile.
+shapes, spans as profiler annotations, and the retrace watchdog firing on
+a forced recompile.
 
 Serve level: a metrics-enabled serve emits per-tier density, per-layer
 alpha, pool pressure, and latency percentiles to every sink; is BITWISE
 identical (tokens + controller telemetry) to the same queue served with
-the hub disabled; stamps spans from the FaultInjector virtual clock when
-one is armed; and stays retrace-silent across a warmed bucket-ladder
-sweep — the ISSUE 9 acceptance bar.
+the hub disabled; times span histograms on the FaultInjector virtual
+clock when one is armed; stays retrace-silent across a warmed
+bucket-ladder sweep; and lays one tree of ``serve.*`` spans per serve
+loop pass into a ``jax.profiler`` capture.
 """
+import glob
 import json
 import math
-import os
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData, StepTraceAnnotation, TraceAnnotation
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -32,8 +34,8 @@ from repro.configs.registry import default_sparse
 from repro.models import lm
 from repro.runtime.faults import FaultInjector
 from repro.runtime.metrics import (DEFAULT_BUCKETS, Histogram, MetricsHub,
-                                   _NULL_SPAN, nearest_rank_pct,
-                                   validate_jsonl)
+                                   RetraceWatchdog, _Span, capture,
+                                   nearest_rank_pct, validate_jsonl)
 from repro.runtime.server import Request, Server, ServeConfig
 
 jax.config.update("jax_platform_name", "cpu")
@@ -238,13 +240,13 @@ class TestHubInstruments:
         assert hub.hist_count("latency_s", tier="fast") == 3
         assert hub.hist_count("latency_s") == 0
 
-    def test_complete_records_duration(self):
+    def test_span_records_duration(self):
         ticks = iter([10.0, 10.5])
         hub = enabled_hub()
         hub.bind_clock(lambda: next(ticks))
-        t0 = hub.now()
-        hub.complete("phase", t0, hist="phase_s")
-        assert hub.hist_mean("phase_s") == pytest.approx(0.5)
+        with hub.span("serve.phase", hist="phase_s", uid=3):
+            pass
+        assert hub.hist_mean("phase_s", uid=3) == pytest.approx(0.5)
 
     def test_disabled_hub_is_noop(self):
         hub = MetricsHub(MetricsConfig())        # enabled=False default
@@ -253,17 +255,24 @@ class TestHubInstruments:
         hub.set_gauge("g", 1.0)
         hub.observe("h", 1.0)
         hub.event("e")
-        hub.complete("p", 0.0, hist="p_s")
-        assert hub.span("s") is _NULL_SPAN
+        with hub.span("s", hist="p_s"):
+            pass
+        assert isinstance(hub.span("s", hist="p_s"), TraceAnnotation)
         assert hub.events() == []
         snap = hub.snapshot()
         assert snap["counters"] == {} and snap["gauges"] == {} \
             and snap["histograms"] == {}
 
     def test_span_without_hist_or_trace_is_null(self):
-        hub = enabled_hub()                       # trace off
-        assert hub.span("s") is _NULL_SPAN
-        assert hub.span("s", hist="s_s") is not _NULL_SPAN
+        # no histogram to fold into (or a disabled hub): the span is the
+        # bare profiler annotation, no clock read on the hub's side
+        hub = enabled_hub()
+        assert type(hub.span("s")) is TraceAnnotation
+        assert type(hub.span("s", uid=1)) is TraceAnnotation
+        assert isinstance(hub.span("s", step_num=2), StepTraceAnnotation)
+        assert isinstance(hub.span("s", hist="s_s"), _Span)
+        off = MetricsHub(MetricsConfig())
+        assert type(off.span("s", hist="s_s")) is TraceAnnotation
 
     def test_config_validation(self):
         with pytest.raises(ValueError):
@@ -275,7 +284,7 @@ class TestHubInstruments:
 
 
 # ---------------------------------------------------------------------------
-# sinks: JSONL, exposition, trace
+# sinks: JSONL, exposition
 # ---------------------------------------------------------------------------
 
 class TestSinks:
@@ -326,30 +335,6 @@ class TestSinks:
         assert "sparseinfer_latency_s_sum 10" in lines
         assert "sparseinfer_latency_s_count 4" in lines
         assert "sparseinfer_retraces_post_warmup 0" in lines
-
-    def test_trace_well_formed(self, tmp_path):
-        hub = enabled_hub(trace=True)
-        with hub.span("prefill", slot=0):
-            pass
-        with hub.span("decode_step"):
-            pass
-        hub.instant("shed", uid=3)
-        doc = hub.trace_events()
-        assert doc["displayTimeUnit"] == "ms"
-        evs = doc["traceEvents"]
-        assert len(evs) == 3
-        for ev in evs:
-            assert ev["ph"] in ("X", "i")
-            assert isinstance(ev["ts"], float) and ev["ts"] >= 0.0
-            assert isinstance(ev["pid"], int) and isinstance(ev["tid"], int)
-            if ev["ph"] == "X":
-                assert ev["dur"] >= 0.0
-        # one tid row per distinct phase name
-        assert len({e["tid"] for e in evs}) == 3
-        assert evs[0]["args"] == {"slot": 0}
-        path = str(tmp_path / "trace.json")
-        hub.write_trace(path)
-        assert json.load(open(path))["traceEvents"]
 
     def test_snapshot_shape(self):
         hub = enabled_hub()
@@ -436,10 +421,8 @@ def _mk_server(mcfg=None, paged=False, buckets=None, **over):
 class TestServeMetrics:
     def test_serve_emits_every_family(self, tmp_path):
         jl = str(tmp_path / "m.jsonl")
-        tr = str(tmp_path / "t.json")
         sn = str(tmp_path / "s.prom")
         srv = _mk_server(MetricsConfig(enabled=True, jsonl_path=jl,
-                                       trace=True, trace_path=tr,
                                        snapshot_path=sn, cadence=2),
                          paged=True)
         try:
@@ -466,9 +449,13 @@ class TestServeMetrics:
             kinds = {e["kind"] for e in hub.events()}
             assert {"serve_start", "admit", "first_token", "complete",
                     "serve_end"} <= kinds
-            doc = json.load(open(tr))
-            names = {e["name"] for e in doc["traceEvents"]}
-            assert "prefill" in names and "decode_step" in names
+            # the spans' histograms: monolithic prefill per request,
+            # decode steps and controller updates
+            hists = snap["histograms"]
+            assert sum(h["count"] for k, h in hists.items()
+                       if k.startswith("prefill_s{")) == 4
+            assert hub.hist_count("controller_update_s") \
+                == hub.hist_count("decode_step_s")
             expo = open(sn).read()
             assert "sparseinfer_tier_realized_density" in expo
             assert ('sparseinfer_latency_s'
@@ -493,14 +480,14 @@ class TestServeMetrics:
                          "predicted_ema", "union_ema", "overflow_ema"):
                 assert np.array_equal(getattr(s_on, name),
                                       getattr(s_off, name)), name
-            assert srv_off.metrics.span("x") is _NULL_SPAN
+            assert type(srv_off.metrics.span("x", hist="x_s")) \
+                is TraceAnnotation
         finally:
             srv_on.metrics.close()
 
     def test_virtual_clock_spans(self, tmp_path):
         jl = str(tmp_path / "v.jsonl")
-        srv = _mk_server(MetricsConfig(enabled=True, jsonl_path=jl,
-                                       trace=True))
+        srv = _mk_server(MetricsConfig(enabled=True, jsonl_path=jl))
         try:
             tick = 0.05
             srv.attach_faults(FaultInjector(seed=0, virtual_clock=True,
@@ -515,12 +502,17 @@ class TestServeMetrics:
             for t in ts:
                 frac = (t - 1.0) / tick
                 assert abs(frac - round(frac)) < 1e-6, t
-            # the virtual clock does not advance INSIDE a phase, so spans
-            # are zero-duration and histograms carry zero totals
-            assert hub.hist_mean("decode_step_s") == 0.0
-            for ev in hub.trace_events()["traceEvents"]:
-                if ev["ph"] == "X":
-                    assert ev["dur"] == 0.0
+            # the virtual clock does not advance INSIDE a phase, so every
+            # span histogram observes zero-duration phases
+            hists = hub.snapshot()["histograms"]
+            spans = {k: h for k, h in hists.items()
+                     if k.split("{")[0] in ("prefill_s", "decode_step_s",
+                                            "controller_update_s")}
+            assert {k.split("{")[0] for k in spans} == {
+                "prefill_s", "decode_step_s", "controller_update_s"}
+            for k, h in spans.items():
+                assert h["count"] > 0 and h["sum"] == 0.0, k
+                assert h["max"] == 0.0, k
             # latency percentiles are exact tick multiples, not CPU noise
             p95 = hub.percentile("latency_s", 0.95, tier="balanced")
             frac = p95 / tick
@@ -556,3 +548,104 @@ class TestServeMetrics:
             assert trep["p95_latency_s"] == lats[-1]
         finally:
             srv.metrics.close()
+
+
+# ---------------------------------------------------------------------------
+# spans in a jax.profiler capture, with the hub disabled
+# ---------------------------------------------------------------------------
+
+LOOP_SPANS = {"serve.iteration", "serve.schedule", "serve.admit",
+              "serve.prefill_chunk", "serve.put_slots", "serve.decode",
+              "serve.decode_dispatch", "serve.sync", "serve.commit"}
+
+
+def _host_spans(log_dir) -> list:
+    """[(name, start_ns, end_ns, stats)] of every serve.* span captured."""
+    path, = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                {k: v for k, v in ev.stats}))
+    return out
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+class TestProfilerSpans:
+    @pytest.mark.parametrize("controller", [False, True])
+    def test_capture_holds_one_span_tree_per_pass(self, tmp_path,
+                                                  controller):
+        srv = _mk_server(controller=ControllerConfig(enabled=controller,
+                                                     per_tier=True),
+                         prefill_chunk=4)
+        assert not srv.metrics.enabled
+        srv.serve(_mk_requests())                # compiles outside the capture
+        reqs = _mk_requests()
+        with capture(str(tmp_path)):
+            srv.serve(reqs)
+        spans = _host_spans(tmp_path)
+        names = {s[0] for s in spans}
+        want = LOOP_SPANS | ({"serve.controller"} if controller else set())
+        assert want <= names, want - names
+        iters = [s for s in spans if s[0] == "serve.iteration"]
+        assert [s[3]["step_num"] for s in iters] == list(range(len(iters)))
+        # every other span lies inside the pass that opened it
+        for s in spans:
+            if s[0] != "serve.iteration":
+                assert sum(_inside(s, it) for it in iters) == 1, s
+        # one wait for the step's tokens per decode; the controller's
+        # update holds the wait for its telemetry
+        decodes = [s for s in spans if s[0] == "serve.decode"]
+        ctrl_updates = [s for s in spans if s[0] == "serve.controller"
+                        and any(_inside(y, s) for y in spans
+                                if y[0] == "serve.sync")]
+        for d in decodes:
+            syncs = [y for y in spans if y[0] == "serve.sync"
+                     and _inside(y, d)
+                     and not any(_inside(y, c) for c in ctrl_updates)]
+            assert len(syncs) == 1, d
+        assert len(ctrl_updates) == (len(decodes) if controller else 0)
+        for it in iters:
+            if any(_inside(d, it) for d in decodes):
+                assert any(y[0] == "serve.sync" and _inside(y, it)
+                           for y in spans)
+        # admissions and chunks carry the request's uid and slot
+        uids = {r.uid for r in reqs}
+        for name in ("serve.admit", "serve.prefill_chunk"):
+            tagged = [s for s in spans if s[0] == name]
+            assert {s[3]["uid"] for s in tagged} == uids, name
+            assert all(s[3]["slot"] in (0, 1) for s in tagged)
+
+    @pytest.mark.parametrize("paged", [False, True])
+    def test_spans_leave_tokens_and_traces_unchanged(self, paged):
+        # no profiler session: a disabled hub's spans are bare annotations,
+        # and the served tokens are bitwise the enabled hub's
+        srv_off = _mk_server(paged=paged, prefill_chunk=8)
+        srv_on = _mk_server(MetricsConfig(enabled=True), paged=paged,
+                            prefill_chunk=8)
+        wd = RetraceWatchdog()
+        wd.install()
+        try:
+            srv_off.serve(_mk_requests())
+            wd.arm()
+            done_off = srv_off.serve(_mk_requests())
+            assert wd.retraces_post_warmup == 0
+            wd.disarm()
+            # the same two serves: the controller adapts across them
+            srv_on.serve(_mk_requests())
+            done_on = srv_on.serve(_mk_requests())
+        finally:
+            wd.uninstall()
+            srv_on.metrics.close()
+        assert srv_on.metrics.watchdog.retraces_post_warmup == 0
+        toks_on = {r.uid: np.asarray(r.out).tolist() for r in done_on}
+        toks_off = {r.uid: np.asarray(r.out).tolist() for r in done_off}
+        assert toks_on == toks_off
