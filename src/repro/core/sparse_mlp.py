@@ -342,9 +342,41 @@ def masked_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
     return y
 
 
+# The MLP matrices a decode layer scan may keep layer-stacked, (L, k, d),
+# for ``gather_mlp(..., layer=)`` (DESIGN.md §3).
+STACKED_MATRICES = ("wg_t", "wu_t", "wd_t")
+
+
+def gathers_from_stack(cfg: SparseInferConfig, params: dict) -> bool:
+    """Whether ``gather_mlp`` can take this MLP's row groups straight out of
+    its layer-stacked matrices: the gather strategy on fp weights with the
+    predictor's sign pack at hand, unsharded (no tp/dp shards, no
+    tensor-parallel mesh axis).  A layer scan then keeps those matrices
+    whole instead of slicing every layer's out of the stack; a slice of a
+    whole matrix costs more bytes than the rows the gather reads."""
+    from repro.sharding import rules as R
+    mesh = R.current_mesh()
+    return (cfg.enabled and cfg.strategy == "gather"
+            and not (cfg.tp_shards or cfg.dp_shards)
+            and "sign_wg" in params and "wg_q" not in params
+            and (mesh is None or not R.tp_axis(mesh)
+                 or R.axis_size(mesh, "model") == 1))
+
+
+def own_layer(params: dict, layer: Optional[jax.Array]) -> dict:
+    """The layer's own matrices out of a node whose ``STACKED_MATRICES`` are
+    layer-stacked; ``layer=None`` means the node is one layer's already."""
+    if layer is None:
+        return params
+    return {kk: (jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+                 if kk in STACKED_MATRICES else v)
+            for kk, v in params.items()}
+
+
 def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
                alpha: float | jax.Array = 1.0,
-               return_stats: bool = False):
+               return_stats: bool = False,
+               layer: Optional[jax.Array] = None):
     """Capacity-gather path (the TPU-shaped algorithm, in XLA ops).
 
     x: (d,) | (B, d) with B <= sparse_max_batch (one union mask), or
@@ -352,6 +384,12 @@ def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
     is the production decode layout (one group per data shard, so each
     device gathers only the rows ITS tokens need; weights are replicated
     across data so the batched gather partitions on the index operand).
+
+    ``layer``: the node's ``STACKED_MATRICES`` are layer-stacked (L, k, d)
+    and this (traced) layer's row groups are gathered straight out of the
+    stack, as rows ``layer * k/g + group`` of its (L*k/g, g, d) view, so
+    no whole matrix is sliced (``gathers_from_stack`` says when that
+    holds); ``sign_wg`` is the layer's own.
     """
     params = _dense_params(params)
     act = _act(cfg)
@@ -360,7 +398,7 @@ def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
     grouped_in = xb.ndim == 3
     xg = xb if grouped_in else xb[None]           # (G, B, d)
     ngrp, b, d = xg.shape
-    k = params["wg_t"].shape[0]
+    k = params["wg_t"].shape[-2]
     g = cfg.group_size
     cap = cfg.capacity(k)
 
@@ -375,6 +413,9 @@ def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
         msz = R.axis_size(mesh, "model")
         if (k // g) % msz == 0 and cap % msz == 0:
             ms = msz
+    if layer is not None and ms > 1:
+        raise ValueError("a layer-stacked gather runs unsharded: "
+                         f"{ms} tensor-parallel shards (DESIGN.md §8)")
 
     with jax.named_scope("predict"):
         m_tok = _margins(params, xg, alpha)       # (G, B, k) per-token
@@ -390,14 +431,17 @@ def gather_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
                               R.shard(sel.valid, None, "model", None),
                               sel.count)
     cl = cap // ms                                # local capacity per shard
+    rows = sel.indices
+    if layer is not None:                         # row of the stacked view
+        rows = rows + layer * (k // g)
 
     @jax.named_scope("gather")
     def take_rows(w_t):
-        w_grouped = w_t.reshape(ms, (k // g) // ms, g, d)
+        w_grouped = w_t.reshape(ms, -1, g, d)     # (ms, [L*]k/g/ms, g, d)
         w_grouped = R.shard(w_grouped, "model", None, None, None)
         # vmap over shards (operand+indices aligned) then over groups
         out = jax.vmap(jax.vmap(S.take_row_groups, in_axes=(0, 0)),
-                       in_axes=(None, 0))(w_grouped, sel.indices)
+                       in_axes=(None, 0))(w_grouped, rows)
         # constrain BEFORE merging (Cl, g): the gather output must stay
         # ms-sharded or the reshape constraint forces an all-gather
         out = R.shard(out, None, "model", None, None, None)
@@ -553,8 +597,13 @@ def pallas_mlp(params: dict, x: jax.Array, cfg: SparseInferConfig,
 def apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
           alpha: jax.Array | float | None = None,
           layer_idx: int = 0, num_layers: int = 1,
-          strategy: Optional[str] = None, **kw) -> Any:
-    """Dispatch the SparseInfer MLP by strategy with the per-layer alpha."""
+          strategy: Optional[str] = None,
+          layer: Optional[jax.Array] = None, **kw) -> Any:
+    """Dispatch the SparseInfer MLP by strategy with the per-layer alpha.
+
+    ``layer``: ``params`` holds layer-stacked matrices (``own_layer``);
+    the gather strategy reads its rows from the stack, the others the
+    layer's own matrices."""
     strategy = strategy or (cfg.strategy if cfg.enabled else "dense")
     if strategy != "dense" and not is_sparsifiable(cfg.activation):
         raise ValueError(
@@ -562,8 +611,11 @@ def apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
             " — run relufication first (repro.core.relufication.relufy)")
     if alpha is None:
         alpha = cfg.alpha_schedule().alpha_for_layer(layer_idx, num_layers)
-    if ((cfg.tp_shards or cfg.dp_shards)
-            and strategy in ("masked", "gather", "pallas")):
+    sharded = bool(cfg.tp_shards or cfg.dp_shards) and strategy in (
+        "masked", "gather", "pallas")
+    if strategy != "gather" or sharded:
+        params, layer = own_layer(params, layer), None
+    if sharded:
         # Shard-local 2D (data × model) formulation (DESIGN.md §8): under an
         # active mesh this runs shard_map over the ('data', 'model') axes;
         # without one the identical math is emulated on a single device.
@@ -576,7 +628,7 @@ def apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
     if strategy == "masked":
         return masked_mlp(params, x, cfg, alpha, **kw)
     if strategy == "gather":
-        return gather_mlp(params, x, cfg, alpha, **kw)
+        return gather_mlp(params, x, cfg, alpha, layer=layer, **kw)
     if strategy == "pallas":
         return pallas_mlp(params, x, cfg, alpha, **kw)
     raise ValueError(f"unknown strategy {strategy!r}")

@@ -23,7 +23,8 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
               *, decode: bool = False, prefill: bool = False,
               alpha: jax.Array | float | None = None,
               layer_idx: int = 0, num_layers: int = 1,
-              return_stats: bool = False):
+              return_stats: bool = False,
+              layer: Optional[jax.Array] = None):
     """x: (..., d). Dense unless (decode and cfg.enabled) or — the
     sequence-axis extension (DESIGN.md §9) — (prefill and cfg.enabled and
     cfg.sparse_prefill), where a chunk's token rows run through the same
@@ -37,6 +38,10 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
     ``return_stats`` additionally yields the strategy's telemetry, exactly
     ``SM.MLP_STAT_KEYS``, one float32 value per token (a fixed pytree that
     stacks under scan).
+    ``layer``: the node's ``SM.STACKED_MATRICES`` are layer-stacked and
+    ``layer`` (traced) picks this layer (``SM.gathers_from_stack``): the
+    gather strategy reads its row groups from the stack, the dense paths
+    the layer's own matrices.
     """
     shape = x.shape
 
@@ -67,7 +72,8 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
         # chunk tokens — sparse prefill under TP/DP stays dense for now
         sparse = False
     if not sparse:
-        return finish(SM.dense_mlp(params, x, cfg, return_stats=return_stats))
+        return finish(SM.dense_mlp(SM.own_layer(params, layer), x, cfg,
+                                   return_stats=return_stats))
     xf = x.reshape(-1, shape[-1])
     # union-mask regime bound is PER-DEVICE tokens (DESIGN.md §2): under a
     # mesh the global batch is sharded over the data axes; tokens are
@@ -79,7 +85,8 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
     # a prefill chunk is many rows; its union bound is its own knob
     max_rows = cfg.prefill_max_tokens if prefill else cfg.sparse_max_batch
     if n > max_rows * dp:
-        out = SM.dense_mlp(params, xf, cfg, return_stats=return_stats)
+        out = SM.dense_mlp(SM.own_layer(params, layer), xf, cfg,
+                           return_stats=return_stats)
     elif (cfg.strategy == "gather" and decode and n > cfg.sparse_max_batch
           and n % dp == 0 and dp > 1
           and not (cfg.tp_shards or cfg.dp_shards)):
@@ -89,7 +96,7 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
         if getattr(ag, "ndim", 0) == 1:          # per-token -> per-group
             ag = ag.reshape(dp, n // dp)
         out = SM.gather_mlp(params, xg, cfg, alpha=ag,
-                            return_stats=return_stats)
+                            return_stats=return_stats, layer=layer)
         if return_stats:
             st = {k: out[1][k].reshape(n) for k in SM.MLP_STAT_KEYS}
             out = (out[0].reshape(n, shape[-1]), st)
@@ -97,5 +104,6 @@ def mlp_apply(params: dict, x: jax.Array, cfg: SparseInferConfig,
             out = out.reshape(n, shape[-1])
     else:
         out = SM.apply(params, xf, cfg, alpha=alpha, layer_idx=layer_idx,
-                       num_layers=num_layers, return_stats=return_stats)
+                       num_layers=num_layers, return_stats=return_stats,
+                       layer=layer)
     return finish(out)

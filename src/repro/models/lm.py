@@ -199,14 +199,17 @@ def _block_fwd(blk, x, cfg: ModelConfig, positions, window, aux,
 
 
 def _block_decode(blk, x, cfg: ModelConfig, cache, cache_len, window, alpha,
-                  lora=None, collect_stats: bool = False, block_table=None):
+                  lora=None, collect_stats: bool = False, block_table=None,
+                  layer=None):
     """One transformer block, single-token decode with KV cache.
 
     Returns ``(x, cache, stats)``; ``stats`` is the MLP telemetry pytree
     (``SM.MLP_STAT_KEYS`` scalars) when ``collect_stats`` else ``None``.
     MoE blocks report zero stats (expert routing is its own control loop).
     ``block_table`` switches the attention onto the paged KV pool (``cache``
-    is then this layer's pool leaves, DESIGN.md §10).
+    is then this layer's pool leaves, DESIGN.md §10).  ``layer``: the MLP
+    node holds the layer-stacked matrices and this traced index picks the
+    layer's rows (``layers.mlp.mlp_apply``).
     """
     from repro.core import sparse_mlp as SM
     with jax.named_scope("attention"):
@@ -239,10 +242,11 @@ def _block_decode(blk, x, cfg: ModelConfig, cache, cache_len, window, alpha,
                                           cfg.sparse.tp_shards)
         elif collect_stats:
             h, stats = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg),
-                                 decode=True, alpha=alpha, return_stats=True)
+                                 decode=True, alpha=alpha, return_stats=True,
+                                 layer=layer)
         else:
             h = mlp_apply(blk["mlp"], h, _mlp_sparse_cfg(cfg), decode=True,
-                          alpha=alpha)
+                          alpha=alpha, layer=layer)
         if cfg.post_block_norm:
             h = C.norm_apply(cfg, blk["ln2_post"], h)
     return x + h, cache, stats
@@ -443,7 +447,14 @@ def _dense_stack_decode(params, x, cfg: ModelConfig, caches, cache_len,
     ``block_table`` (B, nbps): paged-KV mode — ``caches`` leaves are then
     layer-stacked pool blocks (L, N, block, K, hd) instead of per-slot
     dense buffers (DESIGN.md §10); the table is closed over by the scan
-    body (shared by every layer)."""
+    body (shared by every layer).
+
+    Where the MLP's row groups can be gathered straight out of the
+    layer-stacked matrices (``SM.gathers_from_stack``), those matrices are
+    closed over whole and ``xs`` carries the layer index instead: a scan
+    slice of a whole matrix would copy it each step, where the gather
+    reads only the selected rows."""
+    from repro.core import sparse_mlp as SM
     windows = _windows(cfg)
     p = len(windows)
     if alphas is None:
@@ -452,6 +463,15 @@ def _dense_stack_decode(params, x, cfg: ModelConfig, caches, cache_len,
         alphas = jnp.asarray(alphas, jnp.float32)
 
     def run(stacked, caches_s, alphas_s, n):
+        mlp_stack, layers_g = {}, None
+        if ("mlp" in stacked
+                and SM.gathers_from_stack(_mlp_sparse_cfg(cfg),
+                                          stacked["mlp"])):
+            mlp = dict(stacked["mlp"])
+            mlp_stack = {kk: mlp.pop(kk) for kk in SM.STACKED_MATRICES
+                         if kk in mlp}
+            stacked = dict(stacked, mlp=mlp)
+            layers_g = jnp.arange(n, dtype=jnp.int32).reshape(n // p, p)
         grouped = jax.tree.map(
             lambda a: a.reshape((n // p, p) + a.shape[1:]), stacked)
         caches_g = jax.tree.map(
@@ -459,15 +479,20 @@ def _dense_stack_decode(params, x, cfg: ModelConfig, caches, cache_len,
         alphas_g = alphas_s.reshape((n // p, p) + alphas_s.shape[1:])
 
         def body(x, xs):
-            blk_g, cache_g, al = xs
+            blk_g, cache_g, al, lyr = xs
             new_caches, stats = [], []
             for j in range(p):
                 blk = jax.tree.map(lambda a: a[j], blk_g)
                 cache = jax.tree.map(lambda a: a[j], cache_g)
+                layer = None
+                if mlp_stack:
+                    blk["mlp"] = dict(blk["mlp"], **mlp_stack)
+                    layer = lyr[j]
                 x, cache, st = _block_decode(blk, x, cfg, cache, cache_len,
                                              windows[j], al[j],
                                              collect_stats=collect_stats,
-                                             block_table=block_table)
+                                             block_table=block_table,
+                                             layer=layer)
                 new_caches.append(cache)
                 if collect_stats:
                     stats.append(st)
@@ -477,7 +502,7 @@ def _dense_stack_decode(params, x, cfg: ModelConfig, caches, cache_len,
             return x, ys
 
         x2, (new_caches, stats) = jax.lax.scan(
-            body, x, (grouped, caches_g, alphas_g))
+            body, x, (grouped, caches_g, alphas_g, layers_g))
         new_caches = jax.tree.map(
             lambda a: a.reshape((n,) + a.shape[2:]), new_caches)
         if collect_stats:  # (n/p, p, B) -> (n, B) per layer
