@@ -1,15 +1,17 @@
 """Run one benchmark cell: ``python3 bench/run.py --workload <cell> --seed <n>
 --seconds <s> --trace <0|1>`` from the checkout's root, on a TPU.
 
-The cell names a configuration and a traffic mix (``BENCHMARK.json``).  The
-run makes the weights from the seed, builds the server the way
+The cell names a configuration and a traffic mix (``BENCHMARK.json``); the
+configuration names its kit (``bench/kits/``), which makes its weights,
+holds its reference and counts its operations.  The run makes the weights
+from the seed, builds the server the way
 ``python -m repro.launch.serve`` does (``build_config`` -> weights ->
 ``build_server``), warms every program the window runs, then hands waves of
 requests to ``Server.serve`` until ``--seconds`` have passed.  The window
 runs from the first wave's start to the last wave's end; each wave drains
 its slots at its end, which is how the program serves a list.  Afterwards a
-sample of the finished requests is compared with the float32 reference
-(``reference.py``) and one JSON line is printed last on stdout.  With
+sample of the finished requests is compared with the kit's float32
+reference and one JSON line is printed last on stdout.  With
 ``--trace 1`` a few seconds inside the window are traced and the line holds
 the per-layer metrics instead of the end-to-end ones.
 
@@ -112,7 +114,6 @@ class Setup:
 def build(res: dict, seed: int) -> Setup:
     """Weights from the seed and the server the cell's flags describe."""
     import jax
-    from bench import weights
     from repro.launch import serve as LS
     from repro.launch.specs import model_module
     conf, mix = res["config"], res["traffic"]
@@ -124,7 +125,7 @@ def build(res: dict, seed: int) -> Setup:
     args = LS.build_parser().parse_args(flags)
     cfg = LS.build_config(args).replace(**conf["program"]["overrides"])
     mod = model_module(cfg)
-    gen = weights.Generator(conf["model"], conf["weights"])
+    gen = res["kit"].Generator(conf["model"], conf["weights"])
     expected = jax.eval_shape(lambda k: mod.init_lm(k, cfg),
                               jax.random.PRNGKey(0))
     t0 = time.perf_counter()
@@ -248,7 +249,7 @@ def check(setup: Setup, res: dict, seed: int, done: list,
     the gap is read for the tokens the control puts first at each position
     of the same prompts and served tokens, in the served tokens' place (the
     program's own gap stays under ``_program_gap``)."""
-    from bench import reference, traffic
+    from bench import traffic
     conf, mix, limits = res["config"], res["traffic"], res["limits"]
     fin = [r for r in done if r.outcome == "completed"]
     short = sum(1 for r in done if r.outcome != "completed"
@@ -260,8 +261,8 @@ def check(setup: Setup, res: dict, seed: int, done: list,
     outs = [fin[i].out for i in pick]
 
     def ref(fmt=""):
-        return reference.Reference(conf["model"], setup.gen,
-                                   mix["check_bucket"], fmt)
+        return res["kit"].Reference(conf["model"], setup.gen,
+                                    mix["check_bucket"], fmt)
     r0 = ref()
     rows = r0.served(seed, prompts, outs)
     gaps = [float((r[:, 0] - r[:, 1]).max()) for r in rows]
@@ -299,18 +300,20 @@ def free(setup: Setup) -> None:
 
 
 def traced_metrics(res: dict, bm: dict, name: str, setup_cfg, samples,
-                   recs: list, peaks: dict) -> tuple:
+                   recs: list, peaks: dict, root: str = spec.ROOT) -> tuple:
+    """The cell's per-layer metrics, each from its reader.  The readers'
+    ``ctx.token_flops(pos)`` is the kit's count of one decode token at
+    live context ``pos`` and this cell's batch."""
     from bench import tracefile
-    conf = res["config"]
-    sp = setup_cfg.sparse
-    dense = (not sp.enabled) or res["traffic"]["slots"] > sp.sparse_max_batch
-    rows = conf["model"]["d_ff"] if dense else (
-        sp.capacity(setup_cfg.d_ff) * sp.group_size)
-    ctx = argparse.Namespace(samples=samples, device=0, records=recs,
-                             model=conf["model"], mlp_rows=rows, peaks=peaks)
+    kit, model = res["kit"], res["config"]["model"]
+    rows = kit.decode_mlp_rows(model, setup_cfg.sparse,
+                               res["traffic"]["slots"])
+    ctx = argparse.Namespace(
+        samples=samples, device=0, records=recs, peaks=peaks,
+        token_flops=lambda pos: kit.decode_token_flops(model, pos, rows))
     metrics = {}
     for m in spec.per_layer(bm, name):
-        v = spec.reader(m["name"]).read(ctx)
+        v = spec.reader(m["name"], root).read(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     breakdown = {"device_ops": tracefile.top_ops(samples, 0),
@@ -416,7 +419,7 @@ def main(argv=None, require_tpu: bool = True, root: str = spec.ROOT) -> int:
                         a.keep_trace)
         shutil.rmtree(log_dir, ignore_errors=True)
         metrics, breakdown = traced_metrics(res, bm, a.workload, setup.cfg,
-                                            samples, recs, peaks)
+                                            samples, recs, peaks, root)
         info["busy_s"] = tracefile.busy_s(samples, 0)
         info["window_s"] = tracefile.window_s(samples)
         note["modules_seen"] = tracefile.module_names(samples)

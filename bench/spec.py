@@ -7,6 +7,9 @@ per-layer metric sits in a file of its own, found by name:
     bench/traffic/<traffic>.json    serve flags, length distributions, waves
     bench/limits/<cell>.json        limits of the numbers ``correct`` compares
     bench/metrics/<metric>.py       reader of one per-layer metric
+    bench/kits/<kit>.py             weights, reference and operation counts
+                                    of one kind of layer (``kit`` key of a
+                                    configuration file; ``dense`` if none)
 """
 from __future__ import annotations
 
@@ -45,12 +48,14 @@ def config_entry(bm: dict, name: str) -> dict:
 
 
 def resolve(bm: dict, name: str, root: str = ROOT) -> dict:
-    """The cell's entry, configuration, traffic mix and limits."""
+    """The cell's entry, configuration, its kit, traffic mix and limits."""
     w = cell(bm, name)
     c = config_entry(bm, w["config"])
+    conf = load_json(os.path.join(root, c["file"]))
     return {
         "cell": w,
-        "config": load_json(os.path.join(root, c["file"])),
+        "config": conf,
+        "kit": kit(conf, root),
         "traffic": load_json(os.path.join(root, "bench", "traffic",
                                           w["traffic"] + ".json")),
         "limits": load_json(os.path.join(root, "bench", "limits",
@@ -70,12 +75,26 @@ def per_layer(bm: dict, cell_name: str) -> list:
     return [m for m in bm["per_layer"] if applies(m, cell_name)]
 
 
-def reader(metric_name: str, root: str = ROOT):
-    """The module that reads one per-layer metric: ``read(ctx)`` returns
-    its value, or None where the trace holds nothing to read."""
-    path = os.path.join(root, "bench", "metrics", metric_name + ".py")
+def _module(kind: str, name: str, root: str):
+    """``bench/<kind>/<name>.py`` under ``root``, loaded by its path."""
+    if not NAME.match(name):
+        raise ValueError(f"not a benchmark name: {name!r}")
+    path = os.path.join(root, "bench", kind, name + ".py")
     sp = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", metric_name), path)
+        f"bench_{kind}_" + re.sub(r"\W", "_", name), path)
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
     return mod
+
+
+def reader(metric_name: str, root: str = ROOT):
+    """The module that reads one per-layer metric: ``read(ctx)`` returns
+    its value, or None where the trace holds nothing to read."""
+    return _module("metrics", metric_name, root)
+
+
+def kit(conf: dict, root: str = ROOT):
+    """The kit of a configuration file: the module that holds the weight
+    generator, the reference and the operation counts of its kind of layer
+    (``bench/kits/dense.py`` describes what a kit exposes)."""
+    return _module("kits", conf.get("kit", "dense"), root)

@@ -9,8 +9,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from bench import devices, flops  # noqa: E402
+from bench import devices, flops, spec  # noqa: E402
 from bench import run  # noqa: E402
+
+DENSE = spec.kit({})
 
 SMALL = {"n_layers": 2, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
          "head_dim": 4, "d_ff": 32, "vocab_rows": 10, "gated_mlp": True}
@@ -28,6 +30,33 @@ def test_decode_flops_dense_plain_ffn_by_hand():
     m = dict(SMALL, gated_mlp=False)
     # MLP 2 matrices over all 32 rows: 2*8*32*2 = 1024
     assert flops.decode_token_flops(m, 1, 32) == 2 * (384 + 32 + 1024) + 160
+
+
+def test_dense_kit_counts_by_hand():
+    # the two hand counts above, through the kit the configurations name
+    assert DENSE.decode_token_flops(SMALL, 5, 16) == 2 * (384 + 160 + 768) \
+        + 160
+    plain = dict(SMALL, gated_mlp=False)
+    assert DENSE.decode_token_flops(plain, 1, 32) == 2 * (384 + 32 + 1024) \
+        + 160
+
+
+@pytest.mark.parametrize("d_ff,enabled,slots,rows", [
+    # 12288 / 8 = 1536 groups, 20% = 307.2 -> 307, up to a multiple of
+    # 128 / 8 = 16: 320 groups of 8 rows
+    (12288, True, 16, 320 * 8),
+    # 3072 groups -> 614.4 -> 614 -> 624 (granite-34b)
+    (24576, True, 1, 624 * 8),
+    # over sparse_max_batch (16) the program runs the dense MLP
+    (12288, True, 17, 12288),
+    (12288, True, 32, 12288),
+    # sparsity off
+    (12288, False, 16, 12288),
+])
+def test_dense_kit_decode_mlp_rows_by_hand(d_ff, enabled, slots, rows):
+    from repro.core.sparse_mlp import SparseInferConfig
+    sp = SparseInferConfig(enabled=enabled)
+    assert DENSE.decode_mlp_rows({"d_ff": d_ff}, sp, slots) == rows
 
 
 def test_peaks_by_device_kind():

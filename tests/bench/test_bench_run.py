@@ -46,6 +46,8 @@ def root(tmp_path_factory):
                         "traffic": "tiny", "chips": 1, "why": "test"}]
     for d in ("configs", "traffic", "limits"):
         (r / "bench" / d).mkdir(parents=True)
+    shutil.copytree(os.path.join(ROOT, "bench", "kits"), r / "bench" / "kits",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     json.dump(bm, open(r / "BENCHMARK.json", "w"))
     over = {k: TINY_MODEL[k] for k in ("n_layers", "d_model", "n_heads",
                                        "n_kv_heads", "head_dim", "d_ff",
@@ -87,6 +89,22 @@ def test_control_fails_the_check(root, capsys):
     assert res["correct"] is False
     assert res["checks"]["logit_gap"]["value"] > LIMIT
     assert "check logit_gap" in err
+
+
+def test_logit_gaps_are_unchanged_bit_for_bit(root, capsys):
+    """The tiny cell's readings at its seed, bit for bit: the program's gap
+    and the int8 control's, through the kit's weights and reference.  A
+    change to the harness that moves what it serves or compares shows
+    here."""
+    run.main(["--workload", "tiny.chat", "--seed", str(SEED), "--seconds",
+              "1", "--trace", "0", "--control", "int8"], require_tpu=False,
+             root=root)
+    note, res = map(json.loads,
+                    capsys.readouterr().out.strip().splitlines()[-2:])
+    assert note["check"]["_program_gap"] == 0.0
+    assert note["check"]["_argmax_equal"] == 1.0
+    assert note["check"]["_served_tokens"] == 286
+    assert res["checks"]["logit_gap"]["value"] == 0.03591346740722656
 
 
 @pytest.mark.parametrize("fault", faults.NAMES)

@@ -31,8 +31,9 @@ def tr():
 def _ctx(tr, records=()):
     # anchor 0: host time t sits at START + t seconds on the trace's clock
     return argparse.Namespace(
-        samples=[tr], device=0, records=list(records), model=MODEL,
-        mlp_rows=320 * 8, peaks={"bf16_flops": 197e12})
+        samples=[tr], device=0, records=list(records),
+        token_flops=lambda pos: flops.decode_token_flops(MODEL, pos, 320 * 8),
+        peaks={"bf16_flops": 197e12})
 
 
 def test_window_and_programs(tr):
